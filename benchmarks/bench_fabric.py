@@ -11,10 +11,11 @@ Four parts:
    exact, the fabric is cut-through at 1 tick).
 3. **Datapath sweep engine** — a >=32-point receiver-knob grid advanced by
    the jax vmap+scan engine vs the batched-numpy reference vs sequential
-   ``run_sim``; also autotunes the scan ``unroll`` over {1, 4, 8} (cold
-   compile + warm run recorded for each, winner persisted for future
-   processes) and records before (the old hard-coded ``unroll=8``) vs
-   after (autotuned + donated carry) compile and run times.
+   ``run_sim``; also times the scan ``unroll`` over {1, 4, 8} (cold
+   compile + warm run recorded for each; the winner is printed, and
+   nothing is written — the engines run at unroll 1 unless a caller
+   passes another) and records before (the old hard-coded ``unroll=8``)
+   vs after (fastest unroll + donated carry) compile and run times.
 4. **Fabric sweep engine** — a >=32-point *fabric* grid (mode x PFC x
    burst over the incast-8 scenario) advanced by
    ``repro.fabric.vector.run_fabric_sweep`` vs the scalar ``run_fabric``
@@ -76,8 +77,7 @@ import numpy as np
 from repro.core import simulator as S
 from repro.fabric import scenarios as SC
 from repro.fabric import vector as V
-from repro.fabric._scan import (UNROLL_CANDIDATES, configure_persistent_cache,
-                                pick_unroll, save_autotune)
+from repro.fabric._scan import configure_persistent_cache
 from repro.fabric.fused import AdaptiveConfig, program_op_stats
 from repro.fabric.scenarios import fabric_grid
 from repro.fabric.sweep import grid_configs, run_sweep
@@ -90,6 +90,8 @@ PAPER_REF = "§2.1/§6 testbed at fleet scale"
 JSON_PATH = os.path.join(OUT_DIR, "BENCH_fabric.json")
 
 QUICK = False
+
+UNROLL_CANDIDATES = (1, 4, 8)
 
 
 def _sim_time(full: float) -> float:
@@ -142,23 +144,22 @@ def run_sweep_bench() -> List[Dict]:
         cpu_membw_gbps=[1200.0, 1400.0, 1500.0, 1600.0, 1760.0, 1900.0],
         ddio_bytes=[4 << 20, 6 << 20])
 
-    # -- unroll autotune over {1, 4, 8}: cold (compile) + warm per factor -- #
+    # -- unroll timing over {1, 4, 8}: cold (compile) + warm per factor --- #
     times = {}
     for u in UNROLL_CANDIDATES:
         t0 = time.time()
         run_sweep(cfgs, backend="jax", unroll=u)
         cold = time.time() - t0
-        # the winner is persisted (save_autotune) and steers every
-        # later section's scan program — a single noisy warm sample
-        # here must not crown the wrong unroll for the whole process
+        # best-of-N: a single noisy warm sample must not crown the
+        # wrong unroll
         warm, _ = _best_of(lambda: run_sweep(cfgs, backend="jax",
                                              unroll=u))
         times[u] = (cold, warm)
     best = min(times, key=lambda u: times[u][1])
-    save_autotune(best)
 
-    # autotuned, program cached
-    t_warm, jx = _best_of(lambda: run_sweep(cfgs, backend="jax"))
+    # fastest unroll, program cached
+    t_warm, jx = _best_of(lambda: run_sweep(cfgs, backend="jax",
+                                            unroll=best))
     t0 = time.time()
     ref = run_sweep(cfgs, backend="numpy")
     t_np = time.time() - t0
@@ -177,7 +178,7 @@ def run_sweep_bench() -> List[Dict]:
         # either, but compile time dominates the cold number)
         "before_cold_s": times[8][0],
         "before_warm_s": times[8][1],
-        # after: autotuned unroll + donated scan carry
+        # after: fastest unroll + donated scan carry
         "after_cold_s": times[best][0],
         "after_warm_s": t_warm,
         "best_unroll": best,
@@ -215,7 +216,7 @@ def _profile_program(scens, t_cold: float, t_warm: float) -> Dict:
     import jax.numpy as jnp
 
     fsp = V.FabricSweepParams.from_scenarios(scens)
-    fn = V._jax_program(fsp, pick_unroll(None), "ref")
+    fn = V._jax_program(fsp, 1, "ref")
     p_np = V._np_params(fsp, np.float32)
     s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
     stats = program_op_stats(
@@ -316,15 +317,13 @@ def _xla_flops(scens) -> Dict:
     import jax.numpy as jnp
 
     fsp = V.FabricSweepParams.from_scenarios(scens, sparse=True)
-    fn = V._jax_program(fsp, pick_unroll(None), "ref")
+    fn = V._jax_program(fsp, 1, "ref")
     p_np = V._np_params(fsp, np.float32)
     s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
     ca = jax.jit(fn).lower(
         {k: jnp.asarray(v) for k, v in s0.items()},
         {k: jnp.asarray(v) for k, v in p_np.items()}).compile() \
         .cost_analysis()
-    if isinstance(ca, (list, tuple)):          # older jax returns [dict]
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", float("nan"))),
             "ticks": fsp.ticks, "flows": fsp.n_flows,
             "ports": fsp.n_ports, "points": fsp.n_points}
@@ -646,10 +645,8 @@ def run_farm_bench() -> List[Dict]:
     runs single-core in-process dispatch, where chunking can only cost
     a little, never win).  The multiprocess timing re-spawns the worker
     pool per rep, so it includes the real dispatch overhead an
-    overnight run pays; workers share the on-disk XLA cache when
-    ``JAX_COMPILATION_CACHE_DIR`` is set."""
+    overnight run pays; workers share the on-disk XLA cache."""
     import tempfile
-    import warnings as _warnings
 
     from repro.fabric.farm import run_farm
 
@@ -662,14 +659,11 @@ def run_farm_bench() -> List[Dict]:
     t_mono, mono = _best_of(lambda: run_fabric_sweep(scens,
                                                      backend="jax"))
 
-    with _warnings.catch_warnings():
-        # single-device fallback is expected on CI hosts
-        _warnings.simplefilter("ignore", RuntimeWarning)
-        warm = run_farm(scens, workers=0, chunk_size=chunk,
-                        backend="jax", artifacts=False)   # chunk compile
-        t_farm_ip, farm = _best_of(lambda: run_farm(
-            scens, workers=0, chunk_size=chunk, backend="jax",
-            artifacts=False))
+    warm = run_farm(scens, workers=0, chunk_size=chunk,
+                    backend="jax", artifacts=False)   # chunk compile
+    t_farm_ip, farm = _best_of(lambda: run_farm(
+        scens, workers=0, chunk_size=chunk, backend="jax",
+        artifacts=False))
     recompiles = sum(r["compiles"]
                      for r in farm["manifest"]["records"])
 
@@ -723,9 +717,8 @@ def run() -> List[Dict]:
 
 
 def main() -> None:
-    cache = configure_persistent_cache()
-    if cache:
-        print(f"# jax persistent compilation cache: {cache}")
+    print(f"# jax persistent compilation cache: "
+          f"{configure_persistent_cache()}")
     rows = run_incast()
     emit(NAME, rows)
     eq = run_equivalence()

@@ -121,13 +121,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.parallel import collectives as coll
-from repro.parallel.compat import shard_map
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 
 m = 8
-mesh = jax.make_mesh((m,), ("model",))
+mesh = make_mesh((m,), ("model",))
 D, F = 4096, 512          # x:[B=16, D], w:[D, F] sharded on D
 x = jax.ShapeDtypeStruct((16, D), jnp.bfloat16)
 w = jax.ShapeDtypeStruct((D, F), jnp.bfloat16)
@@ -158,10 +159,14 @@ print("JSON:" + json.dumps(rows))
 
 
 def structural() -> List[Dict]:
+    """Compiled collective bytes + temp memory of the ring-staged vs
+    one-shot all-gather matmul, on 8 placeholder CPU devices.  The child
+    is pinned to the CPU backend so it never competes for a chip."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _DRIVER], env=env,
                          capture_output=True, text=True, timeout=600)
     for line in out.stdout.splitlines():
@@ -180,17 +185,14 @@ def main() -> None:
     for c in ("all-to-all", "all-gather", "all-reduce"):
         print(f"# {c}: -{by[c]['improvement_pct']:.1f}% "
               f"(paper -{PAPER_PCT[c]}%)")
-    try:
-        st = structural()
-        emit(NAME + "_structural", st)
-        xla = next(r for r in st if r["impl"] == "xla_allgather")
-        jet = next(r for r in st if r["impl"] == "jet_ring")
-        if xla["temp_bytes"] > 0 and jet["temp_bytes"] > 0:
-            print(f"# jet_ring temp memory {jet['temp_bytes']/1e6:.2f} MB vs "
-                  f"xla all-gather {xla['temp_bytes']/1e6:.2f} MB "
-                  f"(gathered W never materializes)")
-    except Exception as e:  # noqa: BLE001 — structural part is best-effort
-        print(f"# structural sub-benchmark skipped: {e}")
+    st = structural()
+    emit(NAME + "_structural", st)
+    xla = next(r for r in st if r["impl"] == "xla_allgather")
+    jet = next(r for r in st if r["impl"] == "jet_ring")
+    if xla["temp_bytes"] > 0 and jet["temp_bytes"] > 0:
+        print(f"# jet_ring temp memory {jet['temp_bytes']/1e6:.2f} MB vs "
+              f"xla all-gather {xla['temp_bytes']/1e6:.2f} MB "
+              f"(gathered W never materializes)")
 
 
 if __name__ == "__main__":

@@ -1,8 +1,11 @@
 """Pallas kernel microbench: correctness (vs ref oracle) + structural
 roofline terms per kernel.
 
-Wall-clock on CPU is meaningless for TPU kernels, so alongside the
-interpret-mode allclose check we report each kernel's *arithmetic intensity*
+The correctness check runs each kernel compiled (``impl="pallas"``) when
+jax's backend is a TPU and under the Pallas interpreter elsewhere; the
+``impl`` column records which ran.  Wall-clock off the chip is meaningless
+for TPU kernels, so alongside the allclose check we report each kernel's
+*arithmetic intensity*
 (FLOPs / HBM bytes) at production shapes and its implied roofline bound on a
 v5e chip (197 TFLOP/s bf16, 819 GB/s HBM) — the number the BlockSpec tiling
 is designed against.
@@ -69,6 +72,7 @@ def intensity() -> List[Dict]:
 def correctness() -> List[Dict]:
     rows = []
     key = jax.random.key(0)
+    impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
 
     def timed(fn, *a):
         t0 = time.perf_counter()
@@ -78,26 +82,27 @@ def correctness() -> List[Dict]:
     # staged matmul
     a = jax.random.normal(key, (256, 512), jnp.float32)
     b = jax.random.normal(jax.random.key(1), (512, 256), jnp.float32)
-    got, ms_i = timed(lambda x, y: ops.staged_matmul(x, y,
-                                                     impl="interpret"), a, b)
+    got, ms_k = timed(lambda x, y: ops.staged_matmul(x, y, impl=impl), a, b)
     want, ms_r = timed(lambda x, y: ops.staged_matmul(x, y, impl="ref"),
                        a, b)
     err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
     rows.append({"kernel": "staged_matmul", "shape": "256x512x256",
-                 "interpret_ms": ms_i, "ref_ms": ms_r, "max_err": err,
+                 "impl": impl, "kernel_ms": ms_k, "ref_ms": ms_r,
+                 "max_err": err,
                  "ok": int(err < 1e-3)})
 
     # flash attention
     q = jax.random.normal(key, (1, 2, 256, 64), jnp.float32)
     k = jax.random.normal(jax.random.key(2), (1, 2, 256, 64), jnp.float32)
     v = jax.random.normal(jax.random.key(3), (1, 2, 256, 64), jnp.float32)
-    got, ms_i = timed(lambda *t: ops.flash_attention(*t, impl="interpret"),
+    got, ms_k = timed(lambda *t: ops.flash_attention(*t, impl=impl),
                       q, k, v)
     want, ms_r = timed(lambda *t: ops.flash_attention(*t, impl="ref"),
                        q, k, v)
     err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
     rows.append({"kernel": "flash_attention", "shape": "1x2x256x64",
-                 "interpret_ms": ms_i, "ref_ms": ms_r, "max_err": err,
+                 "impl": impl, "kernel_ms": ms_k, "ref_ms": ms_r,
+                 "max_err": err,
                  "ok": int(err < 2e-3)})
 
     # ssd scan
@@ -107,14 +112,15 @@ def correctness() -> List[Dict]:
     a_ = -jnp.exp(jax.random.normal(jax.random.key(5), (h,)))
     b_ = jax.random.normal(jax.random.key(6), (bsz, t, 1, n))
     c_ = jax.random.normal(jax.random.key(7), (bsz, t, 1, n))
-    (got, _), ms_i = timed(lambda *ts: ops.ssd(*ts, chunk=128,
-                                               impl="interpret"),
+    (got, _), ms_k = timed(lambda *ts: ops.ssd(*ts, chunk=128,
+                                               impl=impl),
                            x, dt, a_, b_, c_)
     (want, _), ms_r = timed(lambda *ts: ops.ssd(*ts, chunk=128, impl="ref"),
                             x, dt, a_, b_, c_)
     err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
     rows.append({"kernel": "mamba2_ssd", "shape": f"{bsz}x{t}x{h}x{p}",
-                 "interpret_ms": ms_i, "ref_ms": ms_r, "max_err": err,
+                 "impl": impl, "kernel_ms": ms_k, "ref_ms": ms_r,
+                 "max_err": err,
                  "ok": int(err < 2e-2)})
     return rows
 

@@ -30,6 +30,9 @@ def main() -> None:
     ap.add_argument("--skip", default="")
     args = ap.parse_args()
     skip = set(args.skip.split(",")) if args.skip else set()
+    from repro.fabric._scan import configure_persistent_cache
+    print(f"# jax persistent compilation cache: "
+          f"{configure_persistent_cache()}")
 
     failures = []
     for name, desc in MODULES:

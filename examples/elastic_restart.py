@@ -19,7 +19,7 @@ import numpy as np                                     # noqa: E402
 
 from repro.configs import ARCHS, ShapeConfig, tiny_config  # noqa: E402
 from repro.data import pipeline                        # noqa: E402
-from repro.launch.mesh import ctx_for_mesh             # noqa: E402
+from repro.launch.mesh import ctx_for_mesh, make_mesh  # noqa: E402
 from repro.optim import adamw                          # noqa: E402
 from repro.train import loop as loop_mod               # noqa: E402
 
@@ -34,7 +34,7 @@ def main() -> None:
 
     # ---- phase 1: 8 devices (4 data x 2 model), preempt at step 25 ----
     devs = jax.devices()
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"), devices=devs[:8])
+    mesh8 = make_mesh((4, 2), ("data", "model"), devices=devs[:8])
     ctx8 = ctx_for_mesh(mesh8)
 
     def preempt(step):
@@ -53,7 +53,7 @@ def main() -> None:
         print(">>> preempted at step 25; checkpoint committed")
 
     # ---- phase 2: resume on 4 devices (2x2) — half the fleet ----------
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"), devices=devs[:4])
+    mesh4 = make_mesh((2, 2), ("data", "model"), devices=devs[:4])
     ctx4 = ctx_for_mesh(mesh4)
     print("phase 2: resuming on 4 devices (2x2)")
     with mesh4:

@@ -15,14 +15,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax                                            # noqa: E402
 import jax.numpy as jnp                               # noqa: E402
 import numpy as np                                    # noqa: E402
+from jax import shard_map                             # noqa: E402
 from jax.sharding import PartitionSpec as P           # noqa: E402
 
 from repro.launch import hlo_analysis                 # noqa: E402
+from repro.launch.mesh import make_mesh               # noqa: E402
 from repro.parallel import collectives as coll        # noqa: E402
-from repro.parallel.compat import shard_map           # noqa: E402
 
 M = 8
-MESH = jax.make_mesh((M,), ("model",))
+MESH = make_mesh((M,), ("model",))
 
 
 def report(name, fn, in_specs, args, want, out_specs=P()):

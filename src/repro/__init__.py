@@ -19,7 +19,7 @@ Module map
 - ``models``     architectures (transformer, MoE, SSM, xLSTM) behind one
                  ``api`` for train/prefill/decode.
 - ``parallel``   sharding rules, jet staged collectives, int8+EF grad
-                 compression, pipeline stages, shard_map compat shim.
+                 compression, pipeline stages.
 - ``train``      step construction (FSDP/TP/EP, accum microbatching) and
                  the training loop.
 - ``serving``    batched engine + paged KV cache over the device pool.

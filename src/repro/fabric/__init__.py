@@ -78,7 +78,7 @@
              (`experiments/runs/<run_id>/`: manifest + per-chunk
              result shards + merged table; atomic writes, resume
              contract)
-- _scan:     shared lax.scan compile-cost machinery (unroll autotune,
+- _scan:     shared lax.scan compile-cost machinery (explicit unroll,
              donated carries, persistent XLA compilation cache)
 
 Which engine advances which datapath backend: the scalar driver steps
@@ -237,12 +237,10 @@ as **fixed-shape chunks**:
   changes stride schedules; the farm therefore always runs fixed dt).
 - **Dispatch.**  ``workers <= 1`` stays in-process: a one-deep
   prefetch thread packs chunk k+1 while chunk k computes, and chunks
-  round-robin across local jax devices when
-  ``repro.parallel.compat.farm_dispatch_probe()`` allows (on jax < 0.6
-  or single-device hosts it *warns and degrades* to one device).
-  ``workers > 1`` fans chunks to a ``spawn`` pool; workers rebuild the
-  grid from the registry name (scenario closures don't pickle), share
-  the on-disk XLA cache via ``JAX_COMPILATION_CACHE_DIR``, and write
+  round-robin across ``jax.devices()``.  ``workers > 1`` fans chunks to
+  a ``spawn`` pool (CPU hosts only: a chip belongs to one process);
+  workers rebuild the grid from the registry name (scenario closures
+  don't pickle), share the on-disk XLA compilation cache, and write
   their own shards.
 - **Artifact layout + resume contract.**  Each run writes
   ``experiments/runs/<run_id>/``: ``manifest.json`` (grid spec, chunk

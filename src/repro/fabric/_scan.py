@@ -3,21 +3,13 @@
 Both vectorized engines (:mod:`repro.fabric.sweep` — the single-receiver
 datapath grid — and :mod:`repro.fabric.vector` — the whole-fabric grid)
 are one ``jax.vmap`` + ``lax.scan`` program whose cold-start cost is
-dominated by XLA compiling the scan body.  Two levers live here:
+dominated by XLA compiling the scan body.  Three levers live here:
 
-* **unroll choice.**  ``lax.scan(..., unroll=u)`` duplicates the body
-  ``u`` times: compile time grows roughly linearly with ``u`` while the
-  per-iteration while-loop overhead shrinks.  Measured on the container's
-  CPU backend (jax 0.4.37) the crossover never arrives for these step
-  bodies — a 10k-tick / 36-point datapath sweep compiles in ~1.5 s at
-  ``unroll=1`` vs ~7.4 s at the old hard-coded ``unroll=8`` *and* runs
-  warm ~1.6x faster (0.30 s vs 0.50 s), because the body is already a few
-  hundred fused element-wise ops and the loop overhead is negligible
-  next to their dispatch.  ``pick_unroll`` encodes that as a cached
-  choice: an explicit override (argument or ``REPRO_SCAN_UNROLL``) wins,
-  then a persisted autotune result (``experiments/bench/scan_unroll.json``,
-  written by ``benchmarks/bench_fabric.py`` which times {1, 4, 8} on the
-  real program), then the measured default of 1.
+* **unroll.**  ``lax.scan(..., unroll=u)`` duplicates the body ``u``
+  times: compile time grows roughly linearly with ``u`` while the
+  per-iteration while-loop overhead shrinks.  The unroll is the caller's
+  explicit argument, or 1 — nothing read from disk or the environment
+  changes the compiled program.
 
 * **donated carries.**  The jitted programs take their initial scan
   carry as an argument donated via ``donate_argnums``, so XLA reuses the
@@ -26,71 +18,37 @@ dominated by XLA compiling the scan body.  Two levers live here:
 
 * **persistent compilation cache.**  The step bodies are deterministic
   functions of the grid *structure*, so their XLA executables are
-  reusable across processes.  :func:`configure_persistent_cache` points
-  jax's disk cache at ``JAX_COMPILATION_CACHE_DIR`` (no-op when the env
-  var is unset) and lowers the min-compile-time threshold to 0 s so the
-  quick-mode CI programs are cached too; CI restores the directory via
-  ``actions/cache`` so the fused-kernel compile cost is paid once per
-  toolchain bump, not per push.
+  reusable across processes.  :func:`configure_persistent_cache` (called
+  once by each entry point) points jax's disk cache at
+  ``JAX_COMPILATION_CACHE_DIR`` when it is set, else at the fixed
+  ``<repo>/.jax_cache`` — a stable path, since the path is part of what
+  makes a later process find the entry.
 """
 from __future__ import annotations
 
-import functools
-import json
 import os
-from typing import Optional
 
-UNROLL_CANDIDATES = (1, 4, 8)
-
-# autotune results persisted by benchmarks/bench_fabric.py
-_CACHE_PATH = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "experiments", "bench", "scan_unroll.json")
+REPO_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
 
-@functools.lru_cache(maxsize=None)
-def _cached_autotune() -> Optional[int]:
-    try:
-        with open(_CACHE_PATH) as f:
-            u = int(json.load(f)["unroll"])
-        return u if u in UNROLL_CANDIDATES else None
-    except (OSError, ValueError, KeyError, TypeError):
-        return None
+def pick_unroll(unroll="auto") -> int:
+    """Scan unroll factor: the explicit argument, or 1 for ``"auto"``."""
+    if unroll == "auto":
+        return 1
+    return max(1, int(unroll))
 
 
-def pick_unroll(override: Optional[int] = None) -> int:
-    """Scan unroll factor: override > ``REPRO_SCAN_UNROLL`` env > cached
-    autotune (bench-measured winner over {1, 4, 8}) > measured default 1."""
-    if override is not None:
-        return max(1, int(override))
-    env = os.environ.get("REPRO_SCAN_UNROLL")
-    if env:
-        return max(1, int(env))
-    cached = _cached_autotune()
-    return cached if cached is not None else 1
-
-
-def configure_persistent_cache() -> Optional[str]:
-    """Enable jax's on-disk executable cache when the environment asks
-    for one (``JAX_COMPILATION_CACHE_DIR``).  Returns the cache dir, or
-    None when the env var is unset.  Safe to call before or after other
-    jax work, and idempotent."""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return None
-    cache_dir = os.path.expanduser(cache_dir)
+def configure_persistent_cache() -> str:
+    """Enable jax's on-disk executable cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.jax_cache``.
+    Every program is cached (no minimum compile time).  Idempotent; the
+    only place in the repo that sets ``jax_compilation_cache_dir``."""
+    cache_dir = os.path.expanduser(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR)
     import jax
 
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return cache_dir
-
-
-def save_autotune(unroll: int) -> str:
-    """Persist a bench-measured unroll winner for future processes."""
-    path = os.path.abspath(_CACHE_PATH)
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump({"unroll": int(unroll)}, f)
-    _cached_autotune.cache_clear()
-    return path

@@ -23,13 +23,11 @@ module is the firesim-style run-farm layer on top of it:
   fans chunks out to a ``spawn`` multiprocessing pool — each worker
   rebuilds the grid from a picklable :class:`GridSpec` (scenario objects
   embed receiver-config closures and do not pickle), shares the on-disk
-  XLA compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is set, and
-  writes its own result shards so a killed parent loses nothing.  When
-  several local jax devices exist (and
-  :func:`repro.parallel.compat.farm_dispatch_probe` says the API
-  generation supports it), in-process chunks round-robin across devices;
-  otherwise the farm *degrades with a warning* to single-device chunked
-  execution — never a crash.
+  XLA compilation cache (:func:`repro.fabric._scan
+  .configure_persistent_cache`), and writes its own result shards so a killed parent loses nothing.  A
+  chip belongs to one process, so the pool is CPU-only: on a TPU host
+  ``workers > 1`` with the jax backend is an error.  In-process chunks
+  round-robin over ``jax.devices()`` (one device is a cycle of one).
 
 * **Versioned artifacts + resume.**  Every run writes
   ``experiments/runs/<run_id>/`` (manifest + per-chunk shards + merged
@@ -125,15 +123,16 @@ def _pack_chunk(scens: Sequence, entry: dict, sparse: bool,
     return fsp, n_real
 
 
-def _execute_packed(fsp, n_real: int, backend: str, unroll) -> Tuple[
-        Dict[str, np.ndarray], int]:
-    """Run one packed chunk, slice off padding, count compiles."""
+def _execute_packed(fsp, n_real: int, backend: str, unroll,
+                    device=None) -> Tuple[Dict[str, np.ndarray], int]:
+    """Run one packed chunk (on ``device`` when given), slice off
+    padding, count compiles."""
     c0 = V.PROGRAM_COMPILES
     if backend == "numpy":
         out = V._run_numpy(fsp)
     elif backend == "jax":
         from . import fused
-        out = V._run_jax(fsp, unroll, fused.resolve_impl("auto"))
+        out = V._run_jax(fsp, unroll, fused.resolve_impl("auto"), device)
     else:
         raise ValueError(f"unknown backend {backend!r}")
     out = {k: np.asarray(v)[:n_real] for k, v in out.items()}
@@ -144,15 +143,8 @@ def _execute_packed(fsp, n_real: int, backend: str, unroll) -> Tuple[
 # In-process dispatch (single worker, optional multi-device round-robin)
 # --------------------------------------------------------------------------- #
 def _device_cycle(backend: str):
-    """Devices to round-robin chunks over; [None] = jax's default."""
+    """Devices to round-robin chunks over; [None] = no jax device."""
     if backend != "jax":
-        return [None]
-    from ..parallel import compat
-    ok, reason = compat.farm_dispatch_probe()
-    if not ok:
-        warnings.warn(f"farm device dispatch unavailable ({reason}); "
-                      "falling back to single-device chunked execution",
-                      RuntimeWarning, stacklevel=3)
         return [None]
     import jax
     return list(jax.devices())
@@ -184,14 +176,8 @@ def _run_chunks_inprocess(scens, plan, todo, sparse, envelope, backend,
             entry = plan[k]
             dev = devices[i % len(devices)]
             t0 = time.perf_counter()
-            if dev is None:
-                out, compiles = _execute_packed(fsp, n_real, backend,
-                                                unroll)
-            else:
-                import jax
-                with jax.default_device(dev):
-                    out, compiles = _execute_packed(fsp, n_real,
-                                                    backend, unroll)
+            out, compiles = _execute_packed(fsp, n_real, backend, unroll,
+                                            dev)
             wall = time.perf_counter() - t0
             rec = {"chunk": k, "start": entry["start"],
                    "stop": entry["stop"], "padded": entry["padded"],
@@ -293,6 +279,14 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
     if workers > 1 and not artifacts:
         raise ValueError("multiprocess dispatch requires artifacts "
                          "(workers stream shards to disk)")
+    if workers > 1 and backend == "jax":
+        import jax
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                f"workers={workers} with backend='jax' on a TPU host: a "
+                "chip belongs to one process, so worker processes cannot "
+                "share it — use workers=0 (chunks round-robin over the "
+                "local chips in this process)")
 
     sparse = _pick_sparse(scens, incidence)
     full = V.FabricSweepParams.from_scenarios(scens, sparse=sparse)
@@ -397,6 +391,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="skip chunks whose shards already exist")
     args = ap.parse_args(argv)
 
+    from ._scan import configure_persistent_cache
+    configure_persistent_cache()
     res = run_farm(args.grid, workers=args.workers,
                    chunk_size=args.chunk, backend=args.backend,
                    incidence=args.incidence, quick=args.quick,

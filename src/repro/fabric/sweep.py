@@ -380,11 +380,8 @@ def _jax_program(n_points: int, ticks: int, ring_len: int, dt_us: float,
     call) rather than a traced constant, so ``donate_argnums`` lets XLA
     reuse its buffers — the [P, H] release rings dominate the state —
     instead of holding the zero-init copy alive next to the running
-    carry.  The unroll factor comes from :func:`repro.fabric._scan
-    .pick_unroll`: measured on this stack, ``unroll=1`` beats the old
-    hard-coded 8 both cold (~5x less XLA compile) and warm (~1.6x — the
-    body is already hundreds of fused element-wise ops, so while-loop
-    overhead is negligible and unrolling only bloats the program).
+    carry.  The unroll factor is the caller's, or 1
+    (:func:`repro.fabric._scan.pick_unroll`).
     """
     import jax
     import jax.numpy as jnp
@@ -413,7 +410,7 @@ def _jax_program(n_points: int, ticks: int, ring_len: int, dt_us: float,
 def _run_jax(sp: SweepParams, unroll="auto") -> Dict[str, np.ndarray]:
     import jax.numpy as jnp
 
-    u = pick_unroll(None if unroll == "auto" else unroll)
+    u = pick_unroll(unroll)
     fn = _jax_program(sp.n_points, sp.ticks, sp.ring_len, sp.dt_us, u)
     s0 = _init_state(np, (sp.n_points,), sp.ring_len, sp.vals)
     pv = {k: jnp.asarray(v) for k, v in sp.vals.items()}

@@ -61,6 +61,7 @@ frozen routes *are* the structure.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -829,6 +830,20 @@ class FabricSweepParams:
 # --------------------------------------------------------------------------- #
 # The shared per-tick step (numpy [G, ...] and jax vmapped [...])
 # --------------------------------------------------------------------------- #
+def _contractions(xp):
+    """``(matmul, einsum)`` for namespace ``xp``.  The jax versions run
+    at ``HIGHEST`` precision: at default precision the TPU computes f32
+    products in bf16 passes, and the one-hot products below move byte
+    counts (~1e6) that bf16 would round by ~0.4%.  numpy has no
+    precision argument, and on CPU the f32 product is already exact."""
+    if xp is np:
+        return np.matmul, np.einsum
+    import jax
+    hi = jax.lax.Precision.HIGHEST
+    return (functools.partial(xp.matmul, precision=hi),
+            functools.partial(xp.einsum, precision=hi))
+
+
 def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                opts: Optional[dict] = None):
     """Build ``step(state, t) -> state`` in array namespace ``xp``.
@@ -862,6 +877,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
     # fused-kernel tier for the two priority water-fills ("ref" is the
     # inline formulation; "pallas"/"interpret" need the jnp namespace)
     impl = o.get("impl", "ref") if xp is not np else "ref"
+    mm, es = _contractions(xp)
     f = dtype
     bpt = f(1e9 / 8.0 * dt * 1e-6)       # bytes per (Gbps * tick)
     fdt = f(dt)
@@ -964,7 +980,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
     def class_tot(q0):
         """Per-(port, TC) occupancy [.., Q, P] from per-flow bytes
         [.., P, F] — one small matmul with the class one-hot."""
-        return xp.matmul(clsF, xp.swapaxes(q0, -1, -2))
+        return mm(clsF, xp.swapaxes(q0, -1, -2))
 
     def drain(s, k, upf=None):
         """Stage-k ports forward up to rate*dt: per-class budget grants
@@ -1013,8 +1029,8 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             frac_q = xp.where(is_wrr, frac_wrr, frac_q)
         # scatter per-class grants to (port, flow); one class per flow,
         # so the matmul contraction has a single nonzero term
-        frac_pf = xp.matmul(xp.swapaxes(frac_q, -1, -2), clsF)
-        can_pf = xp.matmul(xp.swapaxes(xp.where(can_q, one, zero),
+        frac_pf = mm(xp.swapaxes(frac_q, -1, -2), clsF)
+        can_pf = mm(xp.swapaxes(xp.where(can_q, one, zero),
                                        -1, -2), clsF)
         out = qm * frac_pf[..., None, :, :]
         qm = qm - out
@@ -1035,7 +1051,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         space_q = xp.maximum(buf_tc - qtc, zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
-        scale_pf = xp.matmul(xp.swapaxes(scale_q, -1, -2), clsF)
+        scale_pf = mm(xp.swapaxes(scale_q, -1, -2), clsF)
         take = A * scale_pf[..., None, :, :]
         lost = (A - take)[..., 0, :, :]
         # fluid go-back-N: tail-dropped bytes re-open the sender's tap
@@ -1046,7 +1062,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             s["inj_lo"] = s["inj_lo"] - lost.sum(-2)
         s["sw_dropped"] = s["sw_dropped"] + lost.sum((-1, -2))
         mark_q = ecn_on[..., None, :] & (qtc > kmin_th)
-        mark_pf = xp.matmul(xp.swapaxes(xp.where(mark_q, one, zero),
+        mark_pf = mm(xp.swapaxes(xp.where(mark_q, one, zero),
                                         -1, -2), clsF)        # [.., P, F]
         dm = xp.where(mark_pf > half,
                       take[..., 0, :, :] - take[..., 1, :, :], zero)
@@ -1214,7 +1230,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             buf_tc - class_tot(s["qm"][..., 0, :, :]), zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
-        scale_pf = xp.matmul(xp.swapaxes(scale_q, -1, -2), clsF)
+        scale_pf = mm(xp.swapaxes(scale_q, -1, -2), clsF)
         take_f = offer * (st["occ"][0] * scale_pf).sum(-2)
         s["inj_lo"] = s["inj_lo"] + take_f
         s["qm"] = s["qm"] + \
@@ -1239,9 +1255,9 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 # per-tick spine selection (run_fabric step 1.5): uplink
                 # occupancy/up-state per candidate as [.., S, F] blocks
                 occP = s["qm"][..., 0, :, :].sum(-1)              # [.., P]
-                occS = xp.einsum('sfp,...p->...sf', st["upP"], occP)
-                up1 = xp.einsum('sfp,...p->...sf', st["upP"], upf)
-                up2 = xp.einsum('sfp,...p->...sf', st["dnP"], upf)
+                occS = es('sfp,...p->...sf', st["upP"], occP)
+                up1 = es('sfp,...p->...sf', st["upP"], upf)
+                up2 = es('sfp,...p->...sf', st["dnP"], upf)
                 upS = st["candS"] & (up1 > half) & (up2 > half)
                 free = xp.where(upS, xp.maximum(bufSF - occS, zero), zero)
                 cur = s["route"]                                  # [.., F]
@@ -1283,7 +1299,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 spray_w = xp.where(totS > zero,
                                    free / xp.maximum(totS, tiny), ch_oh)
                 W = xp.where(m[..., None] == 3, spray_w, ch_oh)
-                D0 = st["dest"][0] + xp.einsum('...sf,sfp->...pf',
+                D0 = st["dest"][0] + es('...sf,sfp->...pf',
                                                W, st["upP"])
             else:
                 D0 = st["dest"][0]
@@ -1313,7 +1329,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             # static [P, F, P] map sends bytes drained at (leaf, spine)
             # to that spine's downlink toward the flow's leaf
             s["tx"] = s["tx"] + out[..., 0, :, :].sum(-1)
-            s = enqueue(s, xp.einsum('...cpf,pfq->...cqf',
+            s = enqueue(s, es('...cpf,pfq->...cqf',
                                      out, st["T1"]))
         else:
             fbm = (st["occ"][1] * out).sum(-2)
@@ -1366,8 +1382,8 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         if any_cc:
             qP = s["qm"][..., 0, :, :].sum(-1)                # [.., P]
             if dyn and Sn:
-                leg1 = xp.einsum('...sf,sfp->...pf', route_oh, st["upP"])
-                leg2 = xp.einsum('...sf,sfp->...pf', route_oh, st["dnP"])
+                leg1 = es('...sf,sfp->...pf', route_oh, st["upP"])
+                leg2 = es('...sf,sfp->...pf', route_oh, st["dnP"])
             elif dyn:
                 leg1 = leg2 = None
             else:
@@ -1618,12 +1634,12 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         # at the port it is queued in: scatter the per-class assert state
         # back to (port, flow), then to that flow's class on its ingress
         # link — [.., Q, P*F] @ [P*F, P] per class
-        assert_pf = xp.matmul(xp.swapaxes(
+        assert_pf = mm(xp.swapaxes(
             xp.where(s["asserted"], one, zero), -1, -2), clsF)
         contrib = xp.where((assert_pf > half) & (q0 > zero), one, zero)
         contrib_q = contrib[..., None, :, :] * clsF[..., :, None, :]
         flat = contrib_q.reshape(contrib_q.shape[:-2] + (-1,))
-        link_paused = xp.matmul(flat, st["prev_mat"]) > zero   # [.., Q, P]
+        link_paused = mm(flat, st["prev_mat"]) > zero   # [.., Q, P]
         link_any = link_paused.any(-2)
         s["pause_us"] = s["pause_us"] + xp.where(link_any, fdt, zero)
         s["pause_tc_us"] = s["pause_tc_us"] + \
@@ -2726,15 +2742,16 @@ def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
     return fn
 
 
-def _run_jax(fsp: FabricSweepParams, unroll, impl: str = "ref"):
-    import jax.numpy as jnp
+def _run_jax(fsp: FabricSweepParams, unroll, impl: str = "ref",
+             device=None):
+    """Run the scan program; ``device`` commits the inputs (and so the
+    execution) to that jax device, else jax's default device."""
+    import jax
 
-    u = pick_unroll(None if unroll == "auto" else unroll)
-    fn = _jax_program(fsp, u, impl)
+    fn = _jax_program(fsp, pick_unroll(unroll), impl)
     p_np = _np_params(fsp, np.float32)
     s0 = _init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
-    p = {k: jnp.asarray(v) for k, v in p_np.items()}
-    final = fn({k: jnp.asarray(v) for k, v in s0.items()}, p)
+    final = fn(jax.device_put(s0, device), jax.device_put(p_np, device))
     return _results({k: np.asarray(v) for k, v in final.items()}, fsp)
 
 

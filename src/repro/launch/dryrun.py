@@ -32,7 +32,7 @@ from ..optim import adamw
 from ..parallel.sharding import ParallelCtx
 from ..train import steps as steps_mod
 from . import hlo_analysis
-from .mesh import ctx_for_mesh, make_production_mesh
+from .mesh import ctx_for_mesh, make_mesh, make_production_mesh
 
 _DTSIZE = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
            "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8, "s16": 2,
@@ -243,7 +243,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
         # for 400B-class decode — see EXPERIMENTS.md §Perf cell C)
         shape = tuple(int(v) for v in variant["mesh_shape"])
         axes = ("pod", "data", "model")[-len(shape):]
-        mesh = jax.make_mesh(shape, axes)
+        mesh = make_mesh(shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     rec = {"arch": arch_name, "shape": shape_name, "mesh": mesh_kind,
@@ -262,9 +262,6 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
             compiled = lowered.compile()
             t2 = time.time()
             cost = compiled.cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):
-                # jax <= 0.4.x returns a one-element list of dicts
-                cost = cost[0] if cost else {}
             mem = compiled.memory_analysis()
             hlo = compiled.as_text()
             coll = collective_bytes(hlo)           # raw (loop-unaware)

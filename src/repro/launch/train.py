@@ -44,12 +44,14 @@ def main() -> None:
 
     from ..configs import get_arch, tiny_config
     from ..data import pipeline
+    from ..fabric._scan import configure_persistent_cache
     from ..configs.base import ShapeConfig
     from ..optim import adamw
     from ..parallel.sharding import single_device_ctx
     from ..train import loop as loop_mod
     from .mesh import ctx_for_mesh, make_mesh, make_production_mesh
 
+    configure_persistent_cache()
     cfg = get_arch(args.arch)
     if args.tiny:
         cfg = tiny_config(cfg)

@@ -83,8 +83,13 @@ def cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray,
     """Mean next-token CE with optional z-loss; logits [..., V] fp32."""
     logits = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, targets[..., None],
-                             axis=-1)[..., 0]
+    # select-and-sum rather than a gather: with vocab-sharded logits
+    # inside a partial-manual shard_map (compressed cross-pod grads)
+    # XLA's SPMD partitioner aborts on the gather; the masked sum
+    # partitions as a local reduce + all-reduce and is exact (one
+    # nonzero term per row)
+    hit = jnp.arange(logits.shape[-1]) == targets[..., None]
+    ll = jnp.where(hit, logits, 0.0).sum(-1)
     loss = jnp.mean(lse - ll)
     if z_loss:
         loss = loss + z_loss * jnp.mean(lse ** 2)

@@ -19,10 +19,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..parallel.compat import shard_map
 from ..parallel.sharding import ParallelCtx
 from .layers import mlp_apply, mlp_init
 

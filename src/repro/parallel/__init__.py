@@ -1,5 +1,4 @@
 """Distribution: sharding rules, jet staged collectives, compression."""
-from .compat import shard_map
 from .sharding import ParallelCtx, single_device_ctx
 
-__all__ = ["ParallelCtx", "shard_map", "single_device_ctx"]
+__all__ = ["ParallelCtx", "single_device_ctx"]

@@ -8,12 +8,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..models import api as model_api
 from ..optim import adamw
-from ..parallel.compat import PARTIAL_MANUAL_SAFE, shard_map
 from ..parallel.sharding import ParallelCtx
 
 # (tp_dim, fsdp_dim) by leaf name, negative indices from the end
@@ -141,19 +141,8 @@ def make_train_step(cfg: ArchConfig, ctx: ParallelCtx,
             out["err"] = state["err"]
         return out, {**metrics, **stats}
 
-    # the manual-'pod' region scans over layers with auto-axis sharding
-    # constraints inside, which legacy jax cannot partition (see compat) —
-    # there the cross-pod sync falls back to exact (uncompressed) pjit.
-    want_pod = (opt_cfg.compressed_pod_grads and ctx.have_mesh
-                and "pod" in ctx.mesh.axis_names)
-    use_pod = want_pod and PARTIAL_MANUAL_SAFE
-    if want_pod and not use_pod:
-        import warnings
-        warnings.warn(
-            "compressed_pod_grads requested but partial-manual shard_map "
-            "is unusable on this jax version; falling back to exact "
-            "(uncompressed) cross-pod gradient sync", RuntimeWarning,
-            stacklevel=2)
+    use_pod = (opt_cfg.compressed_pod_grads and ctx.have_mesh
+               and "pod" in ctx.mesh.axis_names)
     if not use_pod:
         return plain_step
 

@@ -2,6 +2,7 @@
 single-host equivalence with run_sim, vectorized-sweep agreement, and the
 fleet-level incast/HoL phenomenology the fabric exists to reproduce."""
 import math
+import os
 
 import numpy as np
 import pytest
@@ -173,13 +174,37 @@ def test_sweep_rejects_mixed_timebases():
 
 
 def test_sweep_unroll_is_a_pure_perf_knob(sweep_grid):
-    """The scan unroll factor (autotuned by default, see fabric._scan)
+    """The scan unroll factor (1 by default, see fabric._scan)
     must never change results — same program, different loop shape."""
     sample = list(sweep_grid[::12])
     a = run_sweep(sample, backend="jax")          # unroll="auto"
     b = run_sweep(sample, backend="jax", unroll=4)
     for key in ("goodput_gbps", "cnp_count", "dropped_bytes"):
         np.testing.assert_allclose(a[key], b[key], rtol=1e-6)
+
+
+def test_persistent_cache_dir(monkeypatch, tmp_path):
+    """The compile cache goes to ``$JAX_COMPILATION_CACHE_DIR`` when set,
+    else to the fixed ``<repo>/.jax_cache``; the unroll is the explicit
+    argument or 1."""
+    import jax
+    from repro.fabric import _scan
+    old = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert _scan.configure_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert _scan.configure_persistent_cache() == _scan.REPO_CACHE_DIR
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert _scan.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old[1])
+    assert _scan.pick_unroll("auto") == 1
+    assert _scan.pick_unroll(4) == 4
 
 
 # --------------------------------------------------------------------------- #

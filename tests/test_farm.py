@@ -1,5 +1,5 @@
 """Sweep-farm tests: chunk/padding invariance, artifacts + resume, and
-the legacy-jax / single-device fallback.
+device dispatch.
 
 The farm's core promise is that chunking is *invisible*: a grid run as
 one monolithic program, as several chunks, and as chunks padded with
@@ -22,7 +22,6 @@ from repro.fabric.farm import GridSpec, run_farm
 from repro.fabric.scenarios import (build_grid, chunk_plan, incast_grid,
                                     lossy_incast_grid)
 from repro.fabric.vector import FabricSweepParams, run_fabric_sweep
-from repro.parallel import compat
 
 
 def _grid(n=8):
@@ -193,38 +192,20 @@ def test_grid_spec_picklable_and_deterministic():
 
 
 # --------------------------------------------------------------------------- #
-# capability probe + graceful fallback (legacy jax / single device)
+# device dispatch
 # --------------------------------------------------------------------------- #
-def test_farm_dispatch_probe_single_device():
+def test_farm_on_local_devices_bit_identical_vs_monolithic():
+    # chunks round-robin over jax.devices() (a cycle of one on a
+    # single-device host) and each record names the device it ran on
     import jax
-    ok, reason = compat.farm_dispatch_probe(
-        min_devices=len(jax.devices()) + 1)
-    assert not ok
-    assert "device" in reason
-
-
-def test_farm_dispatch_probe_legacy_jax(monkeypatch):
-    # force the legacy-jax path: native shard_map absent must yield a
-    # (False, reason) probe, never an exception
-    monkeypatch.setattr(compat, "_HAS_NATIVE", False)
-    ok, reason = compat.farm_dispatch_probe(min_devices=1)
-    assert not ok
-    assert "legacy jax" in reason
-
-
-def test_farm_degrades_gracefully_without_devices(monkeypatch):
-    # the farm must warn and fall back to single-device chunked
-    # execution — not crash — when device dispatch is unavailable
-    monkeypatch.setattr(compat, "_HAS_NATIVE", False)
-    scens = _grid(4)
+    scens = _grid(8)
     mono = run_fabric_sweep(scens, backend="jax")
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        farm = run_farm(scens, workers=0, chunk_size=4, backend="jax",
-                        artifacts=False)
-    assert any("falling back to single-device" in str(w.message)
-               for w in rec)
-    _assert_identical(mono, farm["results"], "fallback")
+    farm = run_farm(scens, workers=0, chunk_size=2, backend="jax",
+                    artifacts=False)
+    _assert_identical(mono, farm["results"], "local-devices")
+    devs = [str(d) for d in jax.devices()]
+    assert [r["device"] for r in farm["manifest"]["records"]] == \
+        [devs[i % len(devs)] for i in range(4)]
 
 
 def test_raw_scenarios_with_workers_fall_back_inprocess():

@@ -133,14 +133,14 @@ def test_opt_state_specs_rowwise_layout():
     import dataclasses
     from jax.sharding import PartitionSpec as P
     from repro.configs import ARCHS, tiny_config
-    from repro.launch.mesh import ctx_for_mesh
+    from repro.launch.mesh import ctx_for_mesh, make_mesh
     from repro.optim import adamw
     from repro.train import steps as steps_mod
 
     cfg = tiny_config(ARCHS["llama4-scout-17b-a16e"])
     opt_cfg = adamw.OptConfig(int8_moments=True)
     state = steps_mod.abstract_state(cfg, opt_cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = ctx_for_mesh(mesh)
     specs = steps_mod.state_specs(state, ctx)
     flat_p = jax.tree_util.tree_leaves_with_path(state["params"])
