@@ -74,6 +74,8 @@
              local jax devices and/or a multiprocess worker pool, with
              versioned run artifacts and resume — see "Running sweeps
              at farm scale" below
+- spans:     the farm's host spans (profiler annotation + seconds in
+             the manifest record) and transfer counters
 - artifacts: versioned run-artifact layer behind the farm
              (`experiments/runs/<run_id>/`: manifest + per-chunk
              result shards + merged table; atomic writes, resume
@@ -245,7 +247,14 @@ as **fixed-shape chunks**:
 - **Artifact layout + resume contract.**  Each run writes
   ``experiments/runs/<run_id>/``: ``manifest.json`` (grid spec, chunk
   plan, structure envelope + key, config hash, git SHA, engine,
-  per-chunk wall/compile timings, status), ``chunk_NNNN.npz`` shards
+  per-chunk wall/compile timings, status; ``envelope_s``,
+  ``plan_s`` and ``merge_s`` for the run, and per chunk the host spans ``pack_s``,
+  ``pack_wait_s``, ``params_s``, ``h2d_s``, ``dispatch_s``,
+  ``device_s``, ``d2h_s``, ``unpack_s`` and the transfer counters
+  ``h2d_arrays``/``h2d_bytes``, ``d2h_arrays``/``d2h_bytes`` — the
+  same spans land on the ``jax.profiler`` host plane as ``farm.*`` /
+  ``chunk.*`` annotations, see :mod:`repro.fabric.farm`),
+  ``chunk_NNNN.npz`` shards
   (real points only, written atomically), and the merged ``result.npz``
   table in input order.  ``run_farm(..., run_id=..., resume=True)``
   re-reads the manifest, verifies the grid fingerprint, and executes

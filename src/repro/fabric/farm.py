@@ -36,6 +36,36 @@ module is the firesim-style run-farm layer on top of it:
   chunks whose shards are missing or unloadable — kill a run at 50% and
   the restart completes the other half.
 
+* **Spans and counters.**  The host work of a run is cut into spans
+  (:mod:`repro.fabric.spans`), each a ``jax.profiler.TraceAnnotation``
+  (on the profiler's host plane, beside the device trace) and seconds in
+  the manifest, always on.  Each chunk record carries, besides
+  ``wall_s`` (``params`` to ``unpack``; in pool workers from ``pack``
+  on) and ``compiles``:
+
+  - ``pack_s`` (span ``farm.pack``): pad and pack the chunk, on the
+    prefetch thread (in pool workers, inline);
+  - ``pack_wait_s`` (``farm.pack_wait``): the main thread waits for
+    that pack (0 in pool workers);
+  - ``params_s`` (``chunk.params``): program lookup, parameters, zero
+    state;
+  - ``h2d_s`` (``chunk.h2d``): ``jax.device_put`` of state and params;
+  - ``dispatch_s`` (``chunk.dispatch``): enqueue the scan program;
+  - ``device_s`` (``chunk.device``): wait for the chip to finish;
+  - ``d2h_s`` (``chunk.d2h``): pull the final carry to the host;
+  - ``unpack_s`` (``chunk.unpack``): metrics from the carry, padding
+    cut off;
+
+  and the transfer counters ``h2d_arrays``, ``h2d_bytes``,
+  ``d2h_arrays`` and ``d2h_bytes`` (leaves put on and pulled off the
+  device).  The manifest adds ``envelope_s`` (``farm.envelope``: the
+  full grid packed for its envelope), ``plan_s`` (``farm.plan``: the
+  chunk plan and the grid's fingerprint) and ``merge_s``
+  (``farm.merge``: the merged table, and the shard writes of
+  in-process chunks).  Spans on the
+  profiler carry the chunk and device as arguments, transfers their
+  ``arrays`` and ``bytes``.
+
 Command line::
 
     python -m repro.fabric.farm --grid pod_storm --workers 4
@@ -59,6 +89,7 @@ import numpy as np
 from . import artifacts as A
 from . import vector as V
 from .scenarios import build_grid, chunk_plan
+from .spans import RUN_SPANS, field, new_record, span
 
 # set by _worker_init in pool workers; holds the rebuilt grid + run ctx
 _WORKER: dict = {}
@@ -116,26 +147,37 @@ def _pad_chunk(scens: Sequence, entry: dict) -> Tuple[List, int]:
 
 
 def _pack_chunk(scens: Sequence, entry: dict, sparse: bool,
-                envelope: dict):
-    padded, n_real = _pad_chunk(scens, entry)
-    fsp = V.FabricSweepParams.from_scenarios(padded, sparse=sparse,
-                                             envelope=envelope)
+                envelope: dict, record: dict):
+    with span("farm.pack", record):
+        padded, n_real = _pad_chunk(scens, entry)
+        fsp = V.FabricSweepParams.from_scenarios(padded, sparse=sparse,
+                                                 envelope=envelope)
     return fsp, n_real
 
 
+def _chunk_record(entry: dict, device, worker: str) -> dict:
+    return new_record(chunk=entry["chunk"], start=entry["start"],
+                      stop=entry["stop"], padded=entry["padded"],
+                      device=str(device) if device is not None
+                      else "default", worker=worker)
+
+
 def _execute_packed(fsp, n_real: int, backend: str, unroll,
-                    device=None) -> Tuple[Dict[str, np.ndarray], int]:
+                    device=None, record: Optional[dict] = None
+                    ) -> Tuple[Dict[str, np.ndarray], int]:
     """Run one packed chunk (on ``device`` when given), slice off
-    padding, count compiles."""
+    padding, count compiles; spans are timed into ``record``."""
     c0 = V.PROGRAM_COMPILES
     if backend == "numpy":
         out = V._run_numpy(fsp)
     elif backend == "jax":
         from . import fused
-        out = V._run_jax(fsp, unroll, fused.resolve_impl("auto"), device)
+        out = V._run_jax(fsp, unroll, fused.resolve_impl("auto"), device,
+                         record)
     else:
         raise ValueError(f"unknown backend {backend!r}")
-    out = {k: np.asarray(v)[:n_real] for k, v in out.items()}
+    with span("chunk.unpack", record):
+        out = {k: np.asarray(v)[:n_real] for k, v in out.items()}
     return out, V.PROGRAM_COMPILES - c0
 
 
@@ -151,7 +193,8 @@ def _device_cycle(backend: str):
 
 
 def _run_chunks_inprocess(scens, plan, todo, sparse, envelope, backend,
-                          unroll, rdir: Optional[str]) -> List[dict]:
+                          unroll, rdir: Optional[str],
+                          times: dict) -> List[dict]:
     """Execute ``todo`` chunks in this process.
 
     Host-side prep (scenario padding + parameter packing, pure numpy) is
@@ -159,36 +202,33 @@ def _run_chunks_inprocess(scens, plan, todo, sparse, envelope, backend,
     chunk k runs under jax, chunk k+1 is already being packed.  Each
     finished chunk is sliced to its real points and streamed to its
     shard before the next result materializes, so peak memory tracks the
-    chunk shape, not the grid.
+    chunk shape, not the grid.  Shard writes add to ``times["merge_s"]``.
     """
     from concurrent.futures import ThreadPoolExecutor
 
-    devices = _device_cycle(backend)
-    records = []
-    with ThreadPoolExecutor(max_workers=1) as pool:
+    cycle = _device_cycle(backend)
+    devices = [cycle[i % len(cycle)] for i in range(len(todo))]
+    records = [_chunk_record(plan[k], dev, "inprocess")
+               for k, dev in zip(todo, devices)]
+    with ThreadPoolExecutor(max_workers=1,
+                            thread_name_prefix="farm-pack") as pool:
         nxt = pool.submit(_pack_chunk, scens, plan[todo[0]], sparse,
-                          envelope)
-        for i, k in enumerate(todo):
-            fsp, n_real = nxt.result()
+                          envelope, records[0])
+        for i, (k, rec) in enumerate(zip(todo, records)):
+            with span("farm.pack_wait", rec):
+                fsp, n_real = nxt.result()
             if i + 1 < len(todo):
                 nxt = pool.submit(_pack_chunk, scens, plan[todo[i + 1]],
-                                  sparse, envelope)
-            entry = plan[k]
-            dev = devices[i % len(devices)]
+                                  sparse, envelope, records[i + 1])
             t0 = time.perf_counter()
-            out, compiles = _execute_packed(fsp, n_real, backend, unroll,
-                                            dev)
-            wall = time.perf_counter() - t0
-            rec = {"chunk": k, "start": entry["start"],
-                   "stop": entry["stop"], "padded": entry["padded"],
-                   "wall_s": wall, "compiles": compiles,
-                   "device": str(dev) if dev is not None else "default",
-                   "worker": "inprocess"}
+            out, rec["compiles"] = _execute_packed(
+                fsp, n_real, backend, unroll, devices[i], rec)
+            rec["wall_s"] = time.perf_counter() - t0
             if rdir is not None:
-                A.save_chunk(rdir, k, out, meta=rec)
+                with span("farm.merge", times):
+                    A.save_chunk(rdir, k, out, meta=rec)
             else:
                 rec["results"] = out
-            records.append(rec)
     return records
 
 
@@ -211,14 +251,13 @@ def _worker_run_chunk(entry: dict) -> dict:
     """Run one chunk inside a pool worker; writes the shard itself so a
     killed parent cannot lose finished work."""
     w = _WORKER
+    rec = _chunk_record(entry, None, f"pid{os.getpid()}")
     t0 = time.perf_counter()
     fsp, n_real = _pack_chunk(w["scens"], entry, w["sparse"],
-                              w["envelope"])
-    out, compiles = _execute_packed(fsp, n_real, w["backend"], "auto")
-    rec = {"chunk": entry["chunk"], "start": entry["start"],
-           "stop": entry["stop"], "padded": entry["padded"],
-           "wall_s": time.perf_counter() - t0, "compiles": compiles,
-           "device": "default", "worker": f"pid{os.getpid()}"}
+                              w["envelope"], rec)
+    out, rec["compiles"] = _execute_packed(fsp, n_real, w["backend"],
+                                           "auto", record=rec)
+    rec["wall_s"] = time.perf_counter() - t0
     A.save_chunk(w["rdir"], entry["chunk"], out, meta=rec)
     return rec
 
@@ -289,10 +328,13 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
                 "local chips in this process)")
 
     sparse = _pick_sparse(scens, incidence)
-    full = V.FabricSweepParams.from_scenarios(scens, sparse=sparse)
-    envelope = full.envelope()
-    plan = chunk_plan(len(scens), chunk_size)
-    fingerprint = A.config_hash(scens)
+    times = {field(n): 0.0 for n in RUN_SPANS}
+    with span("farm.envelope", times):
+        full = V.FabricSweepParams.from_scenarios(scens, sparse=sparse)
+        envelope = full.envelope()
+    with span("farm.plan", times):
+        plan = chunk_plan(len(scens), chunk_size)
+        fingerprint = A.config_hash(scens)
 
     rdir = None
     done: List[int] = []
@@ -336,13 +378,14 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
         else:
             new_recs = _run_chunks_inprocess(scens, plan, todo, sparse,
                                              envelope, backend, unroll,
-                                             rdir)
+                                             rdir, times)
     else:
         new_recs = []
     wall = time.perf_counter() - t0
 
     if rdir is not None:
-        results = A.merge_chunks(rdir, plan, len(scens))
+        with span("farm.merge", times):
+            results = A.merge_chunks(rdir, plan, len(scens))
         kept = [r for r in manifest["records"]
                 if r["chunk"] not in set(todo)]
         manifest["records"] = sorted(kept + new_recs,
@@ -350,19 +393,22 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
         manifest["status"] = "complete"
         manifest["wall_s"] = wall
         manifest["resumed_chunks"] = sorted(done)
+        manifest.update(times)
         A.write_manifest(rdir, manifest)
     else:
         results: Dict[str, np.ndarray] = {}
-        for rec in new_recs:
-            out = rec.pop("results")
-            for k, v in out.items():
-                if k not in results:
-                    results[k] = np.zeros((len(scens),) + v.shape[1:],
-                                          v.dtype)
-                results[k][rec["start"]:rec["stop"]] = v
+        with span("farm.merge", times):
+            for rec in new_recs:
+                out = rec.pop("results")
+                for k, v in out.items():
+                    if k not in results:
+                        results[k] = np.zeros((len(scens),) + v.shape[1:],
+                                              v.dtype)
+                    results[k][rec["start"]:rec["stop"]] = v
         manifest["records"] = new_recs
         manifest["status"] = "complete"
         manifest["wall_s"] = wall
+        manifest.update(times)
 
     return {"run_id": manifest["run_id"], "run_dir": rdir,
             "manifest": manifest, "results": results,

@@ -76,6 +76,7 @@ from .messages import (HIST_BUCKETS, HIST_MIN_US, MSG_COUNT_EPS, hist_ratio,
                        percentile_from_counts)
 from .topology import NEVER_TICK
 from ._scan import pick_unroll
+from .spans import span, transfer
 from . import fused
 from .fused import AdaptiveConfig
 
@@ -2743,16 +2744,27 @@ def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
 
 
 def _run_jax(fsp: FabricSweepParams, unroll, impl: str = "ref",
-             device=None):
+             device=None, record: Optional[dict] = None):
     """Run the scan program; ``device`` commits the inputs (and so the
-    execution) to that jax device, else jax's default device."""
+    execution) to that jax device, else jax's default device.  The host
+    work is split into the ``chunk.*`` spans of :mod:`.spans`, timed
+    into ``record`` when one is given."""
     import jax
 
-    fn = _jax_program(fsp, pick_unroll(unroll), impl)
-    p_np = _np_params(fsp, np.float32)
-    s0 = _init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
-    final = fn(jax.device_put(s0, device), jax.device_put(p_np, device))
-    return _results({k: np.asarray(v) for k, v in final.items()}, fsp)
+    with span("chunk.params", record):
+        fn = _jax_program(fsp, pick_unroll(unroll), impl)
+        p_np = _np_params(fsp, np.float32)
+        s0 = _init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
+    with transfer("chunk.h2d", record, (s0, p_np)):
+        s0, p = jax.device_put(s0, device), jax.device_put(p_np, device)
+    with span("chunk.dispatch", record):
+        final = fn(s0, p)
+    with span("chunk.device", record):
+        jax.block_until_ready(final)
+    with transfer("chunk.d2h", record, final):
+        final = {k: np.asarray(v) for k, v in final.items()}
+    with span("chunk.unpack", record):
+        return _results(final, fsp)
 
 
 def _jax_adaptive_program(fsp: FabricSweepParams, cfg: AdaptiveConfig,

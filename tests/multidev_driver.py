@@ -387,6 +387,11 @@ def _():
                     artifacts=False)
     recs = farm["manifest"]["records"]
     assert len({r["device"] for r in recs}) == 4, [r["device"] for r in recs]
+    from repro.fabric import spans as S
+    for r in recs:
+        for name in S.CHUNK_SPANS:
+            assert r[S.field(name)] >= 0.0, (r["device"], name)
+        assert r["device_s"] > 0 and r["h2d_arrays"] > r["d2h_arrays"] > 0
     for k in mono:
         assert np.array_equal(np.asarray(mono[k]),
                               np.asarray(farm["results"][k]),

@@ -236,3 +236,81 @@ def test_named_grid_registry():
     assert len(scens) == len(points) == 16
     with pytest.raises(ValueError, match="unknown grid"):
         build_grid("nope")
+
+
+# --------------------------------------------------------------------------- #
+# host spans and transfer counters
+# --------------------------------------------------------------------------- #
+_MAIN_SPANS = ("pack_wait", "params", "h2d", "dispatch", "device", "d2h",
+               "unpack")
+
+
+def _assert_span_fields(rec: dict) -> None:
+    from repro.fabric import spans as S
+    for name in S.CHUNK_SPANS:
+        assert rec[S.field(name)] >= 0.0, name
+    for c in S.TRANSFER_COUNTERS:
+        assert rec[c] >= 0, c
+    # the main thread's spans lie inside the chunk's wall time, but for
+    # the wait on the prefetch thread, which comes before it
+    assert sum(rec[n + "_s"] for n in _MAIN_SPANS) \
+        <= rec["wall_s"] + rec["pack_wait_s"]
+
+
+def _leaves(tree):
+    import jax
+    leaves = jax.tree_util.tree_leaves(tree)
+    return len(leaves), sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                            for x in leaves)
+
+
+def test_chunk_records_carry_spans_and_transfer_counts():
+    """Each chunk record times every span and counts the arrays and bytes
+    of its state and parameters put on the device and of the final carry
+    pulled back, as the program's own packing gives them."""
+    import jax
+    scens = _grid(8)
+    farm = run_farm(scens, workers=0, chunk_size=4, backend="jax",
+                    artifacts=False)
+    m = farm["manifest"]
+    assert m["envelope_s"] > 0 and m["plan_s"] > 0 and m["merge_s"] > 0
+    env = FabricSweepParams.from_scenarios(scens).envelope()
+    fsp = FabricSweepParams.from_scenarios(scens[:4], envelope=env)
+    p = V._np_params(fsp, np.float32)
+    s0 = V._init_state(np, (fsp.n_points,), fsp, p, np.float32)
+    program = V._jax_program(fsp, V.pick_unroll("auto"), "ref")
+    final = jax.eval_shape(program, s0, p)
+    assert len(m["records"]) == 2
+    for rec in m["records"]:
+        _assert_span_fields(rec)
+        assert rec["pack_s"] > 0 and rec["device_s"] > 0
+        assert (rec["h2d_arrays"], rec["h2d_bytes"]) == _leaves((s0, p))
+        assert (rec["d2h_arrays"], rec["d2h_bytes"]) == _leaves(final)
+    assert m["records"][0]["h2d_arrays"] == \
+        len(p) + m["records"][0]["d2h_arrays"]
+
+
+def test_chunk_records_same_fields_on_every_path(tmp_path, monkeypatch):
+    """The numpy backend, the artifacts run and a pool worker's chunk
+    write the same record fields; the numpy engine moves no arrays."""
+    from repro.fabric import farm as F
+    from repro.fabric import spans as S
+    res = run_farm("incast", quick=True, workers=0, chunk_size=8,
+                   backend="numpy", out_dir=str(tmp_path))
+    disk = A.read_manifest(res["run_dir"])
+    assert disk["envelope_s"] > 0 and disk["merge_s"] > 0
+    fields = set(disk["records"][0])
+    for rec in disk["records"]:
+        assert set(rec) == fields
+        _assert_span_fields(rec)
+        assert all(rec[c] == 0 for c in S.TRANSFER_COUNTERS)
+    spec = GridSpec("incast", quick=True)
+    scens, _ = spec.build()
+    env = FabricSweepParams.from_scenarios(scens).envelope()
+    monkeypatch.setattr(F, "_WORKER", dict(
+        scens=scens, sparse=False, envelope=env, backend="numpy",
+        rdir=str(tmp_path / "pool")))
+    rec = F._worker_run_chunk(chunk_plan(len(scens), 8)[1])
+    assert set(rec) == fields and rec["pack_wait_s"] == 0.0
+    assert rec["pack_s"] > 0
+    _assert_span_fields(rec)
