@@ -217,11 +217,8 @@ def _profile_program(scens, t_cold: float, t_warm: float) -> Dict:
 
     fsp = V.FabricSweepParams.from_scenarios(scens)
     fn = V._jax_program(fsp, 1, "ref")
-    p_np = V._np_params(fsp, np.float32)
-    s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
     stats = program_op_stats(
-        fn, {k: jnp.asarray(v) for k, v in s0.items()},
-        {k: jnp.asarray(v) for k, v in p_np.items()})
+        fn, *[jnp.asarray(b) for b in V.packed_params(fsp)])
     return {
         "ticks": fsp.ticks,
         "per_tick_ms_warm": t_warm / fsp.ticks * 1e3,
@@ -318,11 +315,8 @@ def _xla_flops(scens) -> Dict:
 
     fsp = V.FabricSweepParams.from_scenarios(scens, sparse=True)
     fn = V._jax_program(fsp, 1, "ref")
-    p_np = V._np_params(fsp, np.float32)
-    s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
     ca = jax.jit(fn).lower(
-        {k: jnp.asarray(v) for k, v in s0.items()},
-        {k: jnp.asarray(v) for k, v in p_np.items()}).compile() \
+        *[jnp.asarray(b) for b in V.packed_params(fsp)]).compile() \
         .cost_analysis()
     return {"flops": float(ca.get("flops", float("nan"))),
             "ticks": fsp.ticks, "flows": fsp.n_flows,

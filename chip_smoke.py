@@ -135,9 +135,8 @@ def phase_pod_fabric() -> None:
           f"{fsp.n_ports} ports, {fsp.ticks} ticks, sparse engine")
     print(f"  jax: first call {t_cold!r} s (with compile), "
           f"second {t_warm!r} s")
-    p_np = V._np_params(fsp, np.float32)
-    s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
-    _require(_kernel_in_program(V._jax_program(fsp, 1, "pallas"), s0, p_np),
+    _require(_kernel_in_program(V._jax_program(fsp, 1, "pallas"),
+                                *V.packed_params(fsp)),
              "Pallas stages in the compiled fabric program")
     print("  compiled program holds tpu_custom_call (Pallas stages)")
     t0 = time.perf_counter()

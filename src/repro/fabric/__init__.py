@@ -251,7 +251,10 @@ as **fixed-shape chunks**:
   ``plan_s`` and ``merge_s`` for the run, and per chunk the host spans ``pack_s``,
   ``pack_wait_s``, ``params_s``, ``h2d_s``, ``dispatch_s``,
   ``device_s``, ``d2h_s``, ``unpack_s`` and the transfer counters
-  ``h2d_arrays``/``h2d_bytes``, ``d2h_arrays``/``d2h_bytes`` — the
+  ``h2d_arrays``/``h2d_bytes``, ``d2h_arrays``/``d2h_bytes`` (a chunk
+  puts its parameters as one float32 and one int32 buffer, builds its
+  zero carry on the device, and pulls back only the carry keys the
+  metrics read, one float32 buffer plus an int32 one with messages) — the
   same spans land on the ``jax.profiler`` host plane as ``farm.*`` /
   ``chunk.*`` annotations, see :mod:`repro.fabric.farm`),
   ``chunk_NNNN.npz`` shards

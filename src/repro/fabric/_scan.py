@@ -11,10 +11,12 @@ dominated by XLA compiling the scan body.  Three levers live here:
   explicit argument, or 1 — nothing read from disk or the environment
   changes the compiled program.
 
-* **donated carries.**  The jitted programs take their initial scan
-  carry as an argument donated via ``donate_argnums``, so XLA reuses the
-  (grid x ring-horizon) state buffers instead of keeping both the
-  zero-init copy and the running carry alive.
+* **donated carries.**  The single-receiver sweep program and the
+  fabric engine's adaptive program take their initial scan carry as an
+  argument donated via ``donate_argnums``, so XLA reuses the (grid x
+  ring-horizon) state buffers instead of keeping both the zero-init
+  copy and the running carry alive; the fabric scan program builds its
+  zero carry on the device and takes no carry at all.
 
 * **persistent compilation cache.**  The step bodies are deterministic
   functions of the grid *structure*, so their XLA executables are

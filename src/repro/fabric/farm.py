@@ -19,7 +19,7 @@ module is the firesim-style run-farm layer on top of it:
 * **Dispatch.**  ``workers <= 1`` runs chunks in-process with host-side
   chunk packing overlapped against device compute (a one-deep prefetch
   thread builds chunk k+1's parameter pack while chunk k executes; the
-  compiled program itself donates its carry buffers).  ``workers > 1``
+  compiled program builds its zero carry on the device).  ``workers > 1``
   fans chunks out to a ``spawn`` multiprocessing pool — each worker
   rebuilds the grid from a picklable :class:`GridSpec` (scenario objects
   embed receiver-config closures and do not pickle), shares the on-disk
@@ -47,18 +47,21 @@ module is the firesim-style run-farm layer on top of it:
     prefetch thread (in pool workers, inline);
   - ``pack_wait_s`` (``farm.pack_wait``): the main thread waits for
     that pack (0 in pool workers);
-  - ``params_s`` (``chunk.params``): program lookup, parameters, zero
-    state;
-  - ``h2d_s`` (``chunk.h2d``): ``jax.device_put`` of state and params;
+  - ``params_s`` (``chunk.params``): program lookup, parameters
+    packed one buffer per dtype (``vector.packed_params``);
+  - ``h2d_s`` (``chunk.h2d``): ``jax.device_put`` of those buffers,
+    float32 and int32 (the program makes the zero carry itself);
   - ``dispatch_s`` (``chunk.dispatch``): enqueue the scan program;
   - ``device_s`` (``chunk.device``): wait for the chip to finish;
-  - ``d2h_s`` (``chunk.d2h``): pull the final carry to the host;
-  - ``unpack_s`` (``chunk.unpack``): metrics from the carry, padding
+  - ``d2h_s`` (``chunk.d2h``): pull the result buffers, the carry keys
+    the metrics read packed one buffer per dtype (float32, and int32
+    with messages);
+  - ``unpack_s`` (``chunk.unpack``): metrics from those keys, padding
     cut off;
 
   and the transfer counters ``h2d_arrays``, ``h2d_bytes``,
-  ``d2h_arrays`` and ``d2h_bytes`` (leaves put on and pulled off the
-  device).  The manifest adds ``envelope_s`` (``farm.envelope``: the
+  ``d2h_arrays`` and ``d2h_bytes`` (buffers put on and pulled off the
+  device: one or two each way).  The manifest adds ``envelope_s`` (``farm.envelope``: the
   full grid packed for its envelope), ``plan_s`` (``farm.plan``: the
   chunk plan and the grid's fingerprint) and ``merge_s``
   (``farm.merge``: the merged table, and the shard writes of

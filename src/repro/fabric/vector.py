@@ -2696,7 +2696,84 @@ def _run_numpy(fsp: FabricSweepParams, dtype=np.float64,
     return _results(s, fsp)
 
 
-_PROGRAMS: Dict[tuple, Callable] = {}
+# --------------------------------------------------------------------------- #
+# Host-device boundary: one buffer per dtype each way
+# --------------------------------------------------------------------------- #
+_F32, _I32 = np.dtype(np.float32), np.dtype(np.int32)
+
+
+def _result_keys(fsp: FabricSweepParams) -> Tuple[str, ...]:
+    """The carry keys :func:`_results` reads, from the flags
+    :func:`_init_state` builds the carry by."""
+    keys = ["delivered", "deliv_lo", "completion", "ever_paused",
+            "pause_us", "pause_tc_us", "ecn_marked", "sw_dropped",
+            "drained", "cnps", "ecns", "pfc_us", "rnic_drop", "mem_fb"]
+    if fsp.sparse or fsp.dyn_route:
+        keys.append("tx")
+    if fsp.dyn_route:
+        keys.append("reroutes")
+    if fsp.any_msg:
+        keys += ["m_done", "m_hist", "m_lat", "m_over", "m_last"]
+    if fsp.any_flt:
+        keys += ["retx", "flt_drop", "crash_rec", "deadlock"]
+    return tuple(keys)
+
+
+def _layout(arrays, lead: int) -> tuple:
+    """Static layout of a dict of arrays packed one buffer per dtype:
+    ``((buffer dtype, ((key, shape, dtype), ...)), ...)``, shapes without
+    the ``lead`` leading axes.  int32 keys go to an int32 buffer, the
+    rest (float32, and bool as 0/1) to a float32 one; a buffer no key
+    goes to is left out."""
+    groups: Dict[np.dtype, list] = {}
+    for k, v in arrays.items():
+        dt = np.dtype(v.dtype)
+        groups.setdefault(_I32 if dt == _I32 else _F32, []).append(
+            (k, tuple(v.shape[lead:]), dt))
+    return tuple((bd, tuple(groups[bd])) for bd in (_F32, _I32)
+                 if bd in groups)
+
+
+def _pack(xp, layout: tuple, arrays, lead: Tuple[int, ...]) -> tuple:
+    """``arrays`` (leading axes ``lead``) as the buffers of ``layout``,
+    each ``lead + (width,)``."""
+    return tuple(
+        xp.concatenate([xp.reshape(arrays[k], lead + (-1,)).astype(bd)
+                        for k, _, _ in keys], axis=-1)
+        for bd, keys in layout)
+
+
+def _unpack(layout: tuple, bufs, lead: Tuple[int, ...]) -> dict:
+    """The inverse of :func:`_pack`: each key back at its shape and
+    dtype, from static offsets."""
+    out, axis = {}, len(lead)
+    for (_, keys), buf in zip(layout, bufs):
+        off = 0
+        for k, shape, dt in keys:
+            n = int(np.prod(shape, dtype=np.int64))
+            piece = buf[(slice(None),) * axis + (slice(off, off + n),)]
+            out[k] = piece.reshape(lead + shape).astype(dt)
+            off += n
+    return out
+
+
+def _packed_params(fsp: FabricSweepParams) -> Tuple[tuple, tuple]:
+    p = _np_params(fsp, np.float32)
+    layout = _layout(p, 1)
+    return layout, _pack(np, layout, p, (fsp.n_points,))
+
+
+def packed_params(fsp: FabricSweepParams) -> Tuple[np.ndarray, ...]:
+    """The scan program's inputs for ``fsp``: the parameters of
+    :func:`_np_params` as one ``[G, n]`` float32 buffer and one
+    ``[G, m]`` int32 buffer (left out when no parameter is int32).  Call
+    ``_jax_program(fsp, ...)(*packed_params(fsp))``, or lower it on
+    their shapes."""
+    return _packed_params(fsp)[1]
+
+
+# scan programs as (program, result layout); adaptive programs bare
+_PROGRAMS: Dict[tuple, object] = {}
 _PROGRAMS_MAX = 8          # bound compiled-executable memory, as sweep.py
 # monotonic count of program-cache misses (new traces) in this process:
 # the sweep farm's zero-recompile assertion reads it before/after each
@@ -2704,13 +2781,20 @@ _PROGRAMS_MAX = 8          # bound compiled-executable memory, as sweep.py
 PROGRAM_COMPILES = 0
 
 
-def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
+def _program(fsp: FabricSweepParams, unroll: int, impl: str,
+             p_layout: tuple) -> Tuple[Callable, tuple]:
+    """The jitted scan program and the layout of the buffers it returns.
+
+    The program takes the parameter buffers of ``p_layout``, builds the
+    zero carry on the device (:func:`_init_state`, the same float32
+    operations as on the host), runs the vmapped scan and returns the
+    keys of :func:`_result_keys` packed one buffer per dtype."""
     global PROGRAM_COMPILES
     key = (fsp.structure_key, fsp.n_points, fsp.ticks, fsp.ring_len,
-           fsp.cnp_ring, fsp.dt_us, unroll, impl)
-    fn = _PROGRAMS.get(key)
-    if fn is not None:
-        return fn
+           fsp.cnp_ring, fsp.dt_us, unroll, impl, p_layout)
+    hit = _PROGRAMS.get(key)
+    if hit is not None:
+        return hit
     PROGRAM_COMPILES += 1
     import jax
     import jax.numpy as jnp
@@ -2718,6 +2802,7 @@ def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
     dtype = jnp.float32
     st = _static(fsp, jnp, dtype)
     ticks, H, Hc = fsp.ticks, fsp.ring_len, fsp.cnp_ring
+    lead = (fsp.n_points,)
 
     def ring_set(ring, idx, v):
         return ring.at[..., idx, :, :].set(v)
@@ -2734,37 +2819,59 @@ def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
                             unroll=unroll)
         return s
 
-    # the zero-init carry is rebuilt per call, so its (grid x ring) buffers
-    # are donated to the scan instead of staying alive next to it
-    fn = jax.jit(jax.vmap(one_point), donate_argnums=(0,))
+    def start(bufs):
+        # the whole grid's carry, as the host built it before: every key
+        # batched over the grid, so the vmapped scan is unchanged
+        p = _unpack(p_layout, bufs, lead)
+        return _init_state(jnp, lead, fsp, p, dtype), p
+
+    specs = [jax.ShapeDtypeStruct(
+        lead + (sum(int(np.prod(sh)) for _, sh, _ in keys),), bd)
+        for bd, keys in p_layout]
+    s_spec, _ = jax.eval_shape(start, specs)
+    r_layout = _layout({k: s_spec[k] for k in _result_keys(fsp)}, 1)
+
+    def run(*bufs):
+        s0, p = start(bufs)
+        final = jax.vmap(one_point)(s0, p)
+        return _pack(jnp, r_layout, final, lead)
+
+    fn = jax.jit(run)
     while len(_PROGRAMS) >= _PROGRAMS_MAX:
         _PROGRAMS.pop(next(iter(_PROGRAMS)))
-    _PROGRAMS[key] = fn
-    return fn
+    _PROGRAMS[key] = (fn, r_layout)
+    return fn, r_layout
+
+
+def _jax_program(fsp: FabricSweepParams, unroll: int, impl: str = "ref"):
+    """The jitted scan program of ``fsp``: :func:`packed_params` in, the
+    carry keys :func:`_results` reads out, packed one buffer per dtype."""
+    return _program(fsp, unroll, impl, _packed_params(fsp)[0])[0]
 
 
 def _run_jax(fsp: FabricSweepParams, unroll, impl: str = "ref",
              device=None, record: Optional[dict] = None):
     """Run the scan program; ``device`` commits the inputs (and so the
-    execution) to that jax device, else jax's default device.  The host
-    work is split into the ``chunk.*`` spans of :mod:`.spans`, timed
-    into ``record`` when one is given."""
+    execution) to that jax device, else jax's default device.  A chunk
+    crosses the boundary as one buffer per dtype each way: its packed
+    parameters in, the packed carry keys :func:`_results` reads out.
+    The host work is split into the ``chunk.*`` spans of :mod:`.spans`,
+    timed into ``record`` when one is given."""
     import jax
 
     with span("chunk.params", record):
-        fn = _jax_program(fsp, pick_unroll(unroll), impl)
-        p_np = _np_params(fsp, np.float32)
-        s0 = _init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
-    with transfer("chunk.h2d", record, (s0, p_np)):
-        s0, p = jax.device_put(s0, device), jax.device_put(p_np, device)
+        p_layout, bufs = _packed_params(fsp)
+        fn, r_layout = _program(fsp, pick_unroll(unroll), impl, p_layout)
+    with transfer("chunk.h2d", record, bufs):
+        bufs = jax.device_put(bufs, device)
     with span("chunk.dispatch", record):
-        final = fn(s0, p)
+        out = fn(*bufs)
     with span("chunk.device", record):
-        jax.block_until_ready(final)
-    with transfer("chunk.d2h", record, final):
-        final = {k: np.asarray(v) for k, v in final.items()}
+        jax.block_until_ready(out)
+    with transfer("chunk.d2h", record, out):
+        out = [np.asarray(b) for b in out]
     with span("chunk.unpack", record):
-        return _results(final, fsp)
+        return _results(_unpack(r_layout, out, (fsp.n_points,)), fsp)
 
 
 def _jax_adaptive_program(fsp: FabricSweepParams, cfg: AdaptiveConfig,

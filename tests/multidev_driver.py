@@ -391,7 +391,8 @@ def _():
     for r in recs:
         for name in S.CHUNK_SPANS:
             assert r[S.field(name)] >= 0.0, (r["device"], name)
-        assert r["device_s"] > 0 and r["h2d_arrays"] > r["d2h_arrays"] > 0
+        assert r["device_s"] > 0
+        assert 1 <= r["h2d_arrays"] <= 2 and 1 <= r["d2h_arrays"] <= 2
     for k in mono:
         assert np.array_equal(np.asarray(mono[k]),
                               np.asarray(farm["results"][k]),

@@ -113,13 +113,9 @@ def test_priority_admit_pallas(one_chip, no_disk_cache, pod_fsp):
 def test_sparse_scan_program_pallas(one_chip, no_disk_cache, pod_fsp):
     from repro.fabric import vector as V
     fsp = pod_fsp
-    p_np = V._np_params(fsp, np.float32)
-    s0 = V._init_state(np, (fsp.n_points,), fsp, p_np, np.float32)
     fn = V._jax_program(fsp, 1, "pallas")
-    compiled = fn.lower(
-        {k: _sds(v.shape, v.dtype, one_chip) for k, v in s0.items()},
-        {k: _sds(v.shape, v.dtype, one_chip) for k, v in p_np.items()},
-    ).compile()
+    compiled = fn.lower(*[_sds(b.shape, b.dtype, one_chip)
+                          for b in V.packed_params(fsp)]).compile()
     _assert_kernel(compiled)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
