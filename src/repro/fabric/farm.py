@@ -59,10 +59,12 @@ module is the firesim-style run-farm layer on top of it:
   - ``unpack_s`` (``chunk.unpack``): metrics from those keys, padding
     cut off;
 
-  and the transfer counters ``h2d_arrays``, ``h2d_bytes``,
+  the transfer counters ``h2d_arrays``, ``h2d_bytes``,
   ``d2h_arrays`` and ``d2h_bytes`` (buffers put on and pulled off the
-  device: one or two each way).  The manifest adds ``envelope_s`` (``farm.envelope``: the
-  full grid packed for its envelope), ``plan_s`` (``farm.plan``: the
+  device: one or two each way), and the chunk's shape, ``recv_hosts``
+  (receiving hosts, R) and ``flows`` (F).  The manifest adds
+  ``envelope_s`` (``farm.envelope``: the full grid packed for its
+  envelope), ``plan_s`` (``farm.plan``: the
   chunk plan and the grid's fingerprint) and ``merge_s``
   (``farm.merge``: the merged table, and the shard writes of
   in-process chunks).  Spans on the
@@ -155,6 +157,7 @@ def _pack_chunk(scens: Sequence, entry: dict, sparse: bool,
         padded, n_real = _pad_chunk(scens, entry)
         fsp = V.FabricSweepParams.from_scenarios(padded, sparse=sparse,
                                                  envelope=envelope)
+    record.update(recv_hosts=fsp.n_recv, flows=fsp.n_flows)
     return fsp, n_real
 
 

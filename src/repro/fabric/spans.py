@@ -11,6 +11,8 @@
 
 :func:`transfer` is a span over a host-device transfer that also counts
 the arrays and bytes it moves (``h2d_arrays``, ``h2d_bytes``, ...).
+A record also counts the chunk's receiving hosts and flows
+(``recv_hosts``, ``flows``).
 Outside the profiler a span costs about a microsecond.
 """
 from __future__ import annotations
@@ -29,6 +31,9 @@ CHUNK_SPANS = ("farm.pack", "farm.pack_wait", "chunk.params", "chunk.h2d",
 RUN_SPANS = ("farm.envelope", "farm.plan", "farm.merge")
 #: Counters of a chunk's transfers.
 TRANSFER_COUNTERS = ("h2d_arrays", "h2d_bytes", "d2h_arrays", "d2h_bytes")
+#: Counters of a chunk's shape, set when it is packed: receiving hosts
+#: (R) and flows (F), to read per-chunk numbers per receiver or per flow.
+SHAPE_COUNTERS = ("recv_hosts", "flows")
 
 
 def field(name: str) -> str:
@@ -40,7 +45,7 @@ def new_record(**keys) -> dict:
     """A chunk record with every span field and counter at zero."""
     rec = dict(keys)
     rec.update({field(n): 0.0 for n in CHUNK_SPANS})
-    rec.update({c: 0 for c in TRANSFER_COUNTERS})
+    rec.update({c: 0 for c in TRANSFER_COUNTERS + SHAPE_COUNTERS})
     return rec
 
 

@@ -60,6 +60,7 @@ frozen routes *are* the structure.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -79,6 +80,21 @@ from ._scan import pick_unroll
 from .spans import span, transfer
 from . import fused
 from .fused import AdaptiveConfig
+
+#: Name scope of the receive stage (stage 3 and the receivers' CNP
+#: target pick): under jax its ops carry it in their op-name metadata,
+#: so a device trace can put time on the stage.
+RECV_SCOPE = "fabric.recv"
+
+
+def _scope(xp, name: str):
+    """``jax.named_scope(name)`` when tracing with ``jax.numpy``; nothing
+    under numpy.  Op metadata only: the ops themselves are unchanged."""
+    if xp is np:
+        return contextlib.nullcontext()
+    import jax
+    return jax.named_scope(name)
+
 
 _STAGES = 4          # NIC egress, leaf uplink, spine, leaf downlink
 # sparse-incidence stage slots (3-level pod fabrics): NIC egress,
@@ -1435,144 +1451,146 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             s["rc"] = xp.where(ft, rc_tim, xp.where(fh, rc_hp, rc))
 
         # ---- 3. receivers advance one tick (HostDatapath, stacked) -------- #
-        arr_rb = st["recv_onehot"] * arr_b[..., None, :]
-        # QoS-classed arrivals [.., Q, R] (admission class x receiver)
-        arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
-        arr_tot = arr_cr.sum(-2)
-        # admission: RNIC buffer space granted in QoS-priority order —
-        # the second fused priority water-fill (HostDatapath.admit_link)
-        space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
-        acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
-        accepted = acc_cr[..., 0, :]
-        for q_i in range(1, N_QOS):
-            accepted = accepted + acc_cr[..., q_i, :]
-        if flt:
-            # first byte accepted after a crash restart stamps the
-            # crash-recovery latency (run_fabric step 3)
-            rec_hit = (t >= p["crash_until"]) & (accepted > zero) \
-                & xp.isinf(s["crash_rec"])
-            s["crash_rec"] = xp.where(
-                rec_hit, now - p["crash_at"].astype(dtype) * fdt,
-                s["crash_rec"])
-        s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
-        s["qos_q"] = s["qos_q"] + acc_cr
+        with _scope(xp, RECV_SCOPE):
+            arr_rb = st["recv_onehot"] * arr_b[..., None, :]
+            # QoS-classed arrivals [.., Q, R] (admission class x receiver)
+            arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
+            arr_tot = arr_cr.sum(-2)
+            # admission: RNIC buffer space granted in QoS-priority order —
+            # the second fused priority water-fill (HostDatapath.admit_link)
+            space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
+            acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
+            accepted = acc_cr[..., 0, :]
+            for q_i in range(1, N_QOS):
+                accepted = accepted + acc_cr[..., q_i, :]
+            if flt:
+                # first byte accepted after a crash restart stamps the
+                # crash-recovery latency (run_fabric step 3)
+                rec_hit = (t >= p["crash_until"]) & (accepted > zero) \
+                    & xp.isinf(s["crash_rec"])
+                s["crash_rec"] = xp.where(
+                    rec_hit, now - p["crash_at"].astype(dtype) * fdt,
+                    s["crash_rec"])
+            s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
+            s["qos_q"] = s["qos_q"] + acc_cr
 
-        ws = p["qp_bytes"] + s["resident"]
-        miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
-        s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
-        ddio_bw = xp.where(miss > 1e-9,
-                           xp.minimum(p["pcie"],
-                                      avail_dram / (2.0 * miss + tiny)),
-                           p["pcie"])
-        # drain budget granted in QoS-priority order; under Jet pool
-        # pressure (< cache_safe free) the LOW class spills to DRAM (§5)
-        budget = xp.where(jet, jet_cap, ddio_bw * bpt)
-        pool_free = xp.maximum(zero, p["pool"] - s["resident"])
-        spill = jet & (pool_free / p["pool"] < p["safe"])
-        pf = xp.where(jet, pool_free, inf)
-        drained = pool_drained = fallback = zero
-        new_q = []
-        for q_i in range(N_QOS):
-            qq = s["qos_q"][..., q_i, :]
-            take = xp.minimum(xp.minimum(qq, budget), pf)
-            if q_i == N_QOS - 1:        # LOW spills instead of waiting
-                take = xp.where(spill, xp.minimum(qq, budget), take)
-                spilled = xp.where(spill, take, zero)
+            ws = p["qp_bytes"] + s["resident"]
+            miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
+            s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
+            ddio_bw = xp.where(miss > 1e-9,
+                               xp.minimum(p["pcie"],
+                                          avail_dram / (2.0 * miss + tiny)),
+                               p["pcie"])
+            # drain budget granted in QoS-priority order; under Jet pool
+            # pressure (< cache_safe free) the LOW class spills to DRAM (§5)
+            budget = xp.where(jet, jet_cap, ddio_bw * bpt)
+            pool_free = xp.maximum(zero, p["pool"] - s["resident"])
+            spill = jet & (pool_free / p["pool"] < p["safe"])
+            pf = xp.where(jet, pool_free, inf)
+            drained = pool_drained = fallback = zero
+            new_q = []
+            for q_i in range(N_QOS):
+                qq = s["qos_q"][..., q_i, :]
+                take = xp.minimum(xp.minimum(qq, budget), pf)
+                if q_i == N_QOS - 1:        # LOW spills instead of waiting
+                    take = xp.where(spill, xp.minimum(qq, budget), take)
+                    spilled = xp.where(spill, take, zero)
+                else:
+                    spilled = zero
+                pf = pf - (take - spilled)
+                budget = budget - take
+                new_q.append(qq - take)
+                drained = drained + take
+                pool_drained = pool_drained + (take - spilled)
+                fallback = fallback + spilled
+            s["qos_q"] = xp.stack(new_q, -2)
+            s["nic_dram"] = s["nic_dram"] + \
+                xp.where(jet, fallback, drained * 2.0 * miss)
+            s["mem_fb"] = s["mem_fb"] + fallback
+            strag_part = pool_drained * strag_share
+            parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
+                             -2)
+            # ring layout [H, 2, R]: the write is a contiguous leading-axis
+            # slice update, which XLA aliases in place inside the scan carry
+            s["ring"] = ring_set(s["ring"], it % H, parts)
+            s["resident"] = s["resident"] + pool_drained
+            s["strag_res"] = s["strag_res"] + strag_part
+            s["drained"] = s["drained"] + drained
+
+            idx = (it - p["d2"]) % H                  # [.., 2, R]
+            r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
+                                    -3)[..., 0, :, :]
+            r2 = xp.where(it >= p["d2"], r2, zero)
+            for j, is_strag in ((0, False), (1, True)):
+                r = r2[..., j, :]
+                void = xp.minimum(r, s["esc_debt"])
+                s["esc_debt"] = s["esc_debt"] - void
+                r = r - void
+                repay = xp.minimum(void, s["repl_debt"])
+                s["repl_debt"] = s["repl_debt"] - repay
+                s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
+                s["resident"] = xp.maximum(zero, s["resident"] - r)
+                if is_strag:
+                    s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
+
+            # Jet escape ladder (paper Algorithm 1)
+            avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+            esc_on = jet & (avail < p["safe"])
+            can_rep = s["repl_mem"] < p["mem_esc"]
+            x_rep = xp.where(esc_on & can_rep,
+                             xp.maximum(zero,
+                                        xp.minimum(s["strag_res"],
+                                                   p["mem_esc"]
+                                                   - s["repl_mem"])),
+                             zero)
+            s["resident"] = s["resident"] - x_rep
+            s["strag_res"] = s["strag_res"] - x_rep
+            s["esc_debt"] = s["esc_debt"] + x_rep
+            s["repl_debt"] = s["repl_debt"] + x_rep
+            s["repl_mem"] = s["repl_mem"] + x_rep
+            s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
+            s["replaces"] = s["replaces"] + (x_rep > zero)
+            x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
+            s["resident"] = s["resident"] - x_cop
+            s["strag_res"] = s["strag_res"] - x_cop
+            s["esc_debt"] = s["esc_debt"] + x_cop
+            s["esc_dram"] = s["esc_dram"] + x_cop
+            s["copies"] = s["copies"] + (x_cop > zero)
+            avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+            in_danger = esc_on & (avail2 < p["danger"])
+            s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt,
+                                    s["ecn_tus"])
+            esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
+            s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
+            s["cnps"] = s["cnps"] + esc_fire
+            s["ecns"] = s["ecns"] + esc_fire
+            s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
+            s["pool_peak"] = xp.maximum(s["pool_peak"],
+                                        xp.where(jet, s["resident"], zero))
+
+            # receiver congestion signalling
+            q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
+            if host_tc:
+                # per-class receiver gate ([.., Q, R] pause state): per-TC
+                # points watermark each class's occupancy of its 1/N_QOS
+                # buffer partition (ReceiverHost's arithmetic, op for op),
+                # legacy points see the total occupancy in every row —
+                # identical decisions to the scalar whole-link gate
+                frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
+                sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
+                s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
+                                                sel > xoffQ)
+                pfc_any = s["pfc"].any(-2)
             else:
-                spilled = zero
-            pf = pf - (take - spilled)
-            budget = budget - take
-            new_q.append(qq - take)
-            drained = drained + take
-            pool_drained = pool_drained + (take - spilled)
-            fallback = fallback + spilled
-        s["qos_q"] = xp.stack(new_q, -2)
-        s["nic_dram"] = s["nic_dram"] + \
-            xp.where(jet, fallback, drained * 2.0 * miss)
-        s["mem_fb"] = s["mem_fb"] + fallback
-        strag_part = pool_drained * strag_share
-        parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
-                         -2)
-        # ring layout [H, 2, R]: the write is a contiguous leading-axis
-        # slice update, which XLA aliases in place inside the scan carry
-        s["ring"] = ring_set(s["ring"], it % H, parts)
-        s["resident"] = s["resident"] + pool_drained
-        s["strag_res"] = s["strag_res"] + strag_part
-        s["drained"] = s["drained"] + drained
-
-        idx = (it - p["d2"]) % H                  # [.., 2, R]
-        r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
-                                -3)[..., 0, :, :]
-        r2 = xp.where(it >= p["d2"], r2, zero)
-        for j, is_strag in ((0, False), (1, True)):
-            r = r2[..., j, :]
-            void = xp.minimum(r, s["esc_debt"])
-            s["esc_debt"] = s["esc_debt"] - void
-            r = r - void
-            repay = xp.minimum(void, s["repl_debt"])
-            s["repl_debt"] = s["repl_debt"] - repay
-            s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
-            s["resident"] = xp.maximum(zero, s["resident"] - r)
-            if is_strag:
-                s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
-
-        # Jet escape ladder (paper Algorithm 1)
-        avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-        esc_on = jet & (avail < p["safe"])
-        can_rep = s["repl_mem"] < p["mem_esc"]
-        x_rep = xp.where(esc_on & can_rep,
-                         xp.maximum(zero,
-                                    xp.minimum(s["strag_res"],
-                                               p["mem_esc"]
-                                               - s["repl_mem"])),
-                         zero)
-        s["resident"] = s["resident"] - x_rep
-        s["strag_res"] = s["strag_res"] - x_rep
-        s["esc_debt"] = s["esc_debt"] + x_rep
-        s["repl_debt"] = s["repl_debt"] + x_rep
-        s["repl_mem"] = s["repl_mem"] + x_rep
-        s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
-        s["replaces"] = s["replaces"] + (x_rep > zero)
-        x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
-        s["resident"] = s["resident"] - x_cop
-        s["strag_res"] = s["strag_res"] - x_cop
-        s["esc_debt"] = s["esc_debt"] + x_cop
-        s["esc_dram"] = s["esc_dram"] + x_cop
-        s["copies"] = s["copies"] + (x_cop > zero)
-        avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-        in_danger = esc_on & (avail2 < p["danger"])
-        s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt, s["ecn_tus"])
-        esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
-        s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
-        s["cnps"] = s["cnps"] + esc_fire
-        s["ecns"] = s["ecns"] + esc_fire
-        s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
-        s["pool_peak"] = xp.maximum(s["pool_peak"],
-                                    xp.where(jet, s["resident"], zero))
-
-        # receiver congestion signalling
-        q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
-        if host_tc:
-            # per-class receiver gate ([.., Q, R] pause state): per-TC
-            # points watermark each class's occupancy of its 1/N_QOS
-            # buffer partition (ReceiverHost's arithmetic, op for op),
-            # legacy points see the total occupancy in every row —
-            # identical decisions to the scalar whole-link gate
-            frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
-            sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
-            s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
-                                            sel > xoffQ)
-            pfc_any = s["pfc"].any(-2)
-        else:
-            s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
-                                            q_frac > p["xoff"])
-            pfc_any = s["pfc"]
-        s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
-        cnp_tus = s["cnp_tus"] + fdt
-        wm_fire = wm_en & (q_frac > p["ecn_th"]) \
-            & (cnp_tus >= p["cnp_iv"])
-        s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
-        s["cnps"] = s["cnps"] + wm_fire
+                s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
+                                                q_frac > p["xoff"])
+                pfc_any = s["pfc"]
+            s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
+            cnp_tus = s["cnp_tus"] + fdt
+            wm_fire = wm_en & (q_frac > p["ecn_th"]) \
+                & (cnp_tus >= p["cnp_iv"])
+            s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
+            s["cnps"] = s["cnps"] + wm_fire
 
         # ---- 4. feedback routes back to the senders ----------------------- #
         # per-class acceptance share: a flow recovers the share its own
@@ -1594,9 +1612,10 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         # receiver CNPs hit the heaviest recently-arriving flow (lowest
         # flow id on ties); with nothing arriving the previous target
         # stays throttled, as in run_fabric/run_sim
-        has_arr = arr_tot > zero
-        heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
-        s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
+        with _scope(xp, RECV_SCOPE):
+            has_arr = arr_tot > zero
+            heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
+            s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
         is_heavy = arangeF == s["heavy"][..., st["recv_of"]]
         f_esc = is_heavy & esc_fire[..., st["recv_of"]]
         f_wm = is_heavy & wm_fire[..., st["recv_of"]]
@@ -2105,124 +2124,126 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             s["rc"] = xp.where(ft, rc_tim, xp.where(fh, rc_hp, rc))
 
         # ---- 3. receivers advance one tick (HostDatapath, stacked) -------- #
-        arr_rb = st["recv_onehot"] * arr_b[..., None, :]
-        arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
-        arr_tot = arr_cr.sum(-2)
-        space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
-        acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
-        accepted = acc_cr[..., 0, :]
-        for q_i in range(1, N_QOS):
-            accepted = accepted + acc_cr[..., q_i, :]
-        s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
-        s["qos_q"] = s["qos_q"] + acc_cr
+        with _scope(xp, RECV_SCOPE):
+            arr_rb = st["recv_onehot"] * arr_b[..., None, :]
+            arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
+            arr_tot = arr_cr.sum(-2)
+            space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
+            acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
+            accepted = acc_cr[..., 0, :]
+            for q_i in range(1, N_QOS):
+                accepted = accepted + acc_cr[..., q_i, :]
+            s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
+            s["qos_q"] = s["qos_q"] + acc_cr
 
-        ws = p["qp_bytes"] + s["resident"]
-        miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
-        s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
-        ddio_bw = xp.where(miss > 1e-9,
-                           xp.minimum(p["pcie"],
-                                      avail_dram / (2.0 * miss + tiny)),
-                           p["pcie"])
-        budget_r = xp.where(jet, jet_cap, ddio_bw * bpt)
-        pool_free = xp.maximum(zero, p["pool"] - s["resident"])
-        spill = jet & (pool_free / p["pool"] < p["safe"])
-        pf = xp.where(jet, pool_free, inf)
-        drained = pool_drained = fallback = zero
-        new_q = []
-        for q_i in range(N_QOS):
-            qq = s["qos_q"][..., q_i, :]
-            take = xp.minimum(xp.minimum(qq, budget_r), pf)
-            if q_i == N_QOS - 1:        # LOW spills instead of waiting
-                take = xp.where(spill, xp.minimum(qq, budget_r), take)
-                spilled = xp.where(spill, take, zero)
+            ws = p["qp_bytes"] + s["resident"]
+            miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
+            s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
+            ddio_bw = xp.where(miss > 1e-9,
+                               xp.minimum(p["pcie"],
+                                          avail_dram / (2.0 * miss + tiny)),
+                               p["pcie"])
+            budget_r = xp.where(jet, jet_cap, ddio_bw * bpt)
+            pool_free = xp.maximum(zero, p["pool"] - s["resident"])
+            spill = jet & (pool_free / p["pool"] < p["safe"])
+            pf = xp.where(jet, pool_free, inf)
+            drained = pool_drained = fallback = zero
+            new_q = []
+            for q_i in range(N_QOS):
+                qq = s["qos_q"][..., q_i, :]
+                take = xp.minimum(xp.minimum(qq, budget_r), pf)
+                if q_i == N_QOS - 1:        # LOW spills instead of waiting
+                    take = xp.where(spill, xp.minimum(qq, budget_r), take)
+                    spilled = xp.where(spill, take, zero)
+                else:
+                    spilled = zero
+                pf = pf - (take - spilled)
+                budget_r = budget_r - take
+                new_q.append(qq - take)
+                drained = drained + take
+                pool_drained = pool_drained + (take - spilled)
+                fallback = fallback + spilled
+            s["qos_q"] = xp.stack(new_q, -2)
+            s["nic_dram"] = s["nic_dram"] + \
+                xp.where(jet, fallback, drained * 2.0 * miss)
+            s["mem_fb"] = s["mem_fb"] + fallback
+            strag_part = pool_drained * strag_share
+            parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
+                             -2)
+            s["ring"] = ring_set(s["ring"], it % H, parts)
+            s["resident"] = s["resident"] + pool_drained
+            s["strag_res"] = s["strag_res"] + strag_part
+            s["drained"] = s["drained"] + drained
+
+            idx = (it - p["d2"]) % H                  # [.., 2, R]
+            r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
+                                    -3)[..., 0, :, :]
+            r2 = xp.where(it >= p["d2"], r2, zero)
+            for j, is_strag in ((0, False), (1, True)):
+                r = r2[..., j, :]
+                void = xp.minimum(r, s["esc_debt"])
+                s["esc_debt"] = s["esc_debt"] - void
+                r = r - void
+                repay = xp.minimum(void, s["repl_debt"])
+                s["repl_debt"] = s["repl_debt"] - repay
+                s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
+                s["resident"] = xp.maximum(zero, s["resident"] - r)
+                if is_strag:
+                    s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
+
+            # Jet escape ladder (paper Algorithm 1)
+            avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+            esc_on = jet & (avail < p["safe"])
+            can_rep = s["repl_mem"] < p["mem_esc"]
+            x_rep = xp.where(esc_on & can_rep,
+                             xp.maximum(zero,
+                                        xp.minimum(s["strag_res"],
+                                                   p["mem_esc"]
+                                                   - s["repl_mem"])),
+                             zero)
+            s["resident"] = s["resident"] - x_rep
+            s["strag_res"] = s["strag_res"] - x_rep
+            s["esc_debt"] = s["esc_debt"] + x_rep
+            s["repl_debt"] = s["repl_debt"] + x_rep
+            s["repl_mem"] = s["repl_mem"] + x_rep
+            s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
+            s["replaces"] = s["replaces"] + (x_rep > zero)
+            x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
+            s["resident"] = s["resident"] - x_cop
+            s["strag_res"] = s["strag_res"] - x_cop
+            s["esc_debt"] = s["esc_debt"] + x_cop
+            s["esc_dram"] = s["esc_dram"] + x_cop
+            s["copies"] = s["copies"] + (x_cop > zero)
+            avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+            in_danger = esc_on & (avail2 < p["danger"])
+            s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt,
+                                    s["ecn_tus"])
+            esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
+            s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
+            s["cnps"] = s["cnps"] + esc_fire
+            s["ecns"] = s["ecns"] + esc_fire
+            s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
+            s["pool_peak"] = xp.maximum(s["pool_peak"],
+                                        xp.where(jet, s["resident"], zero))
+
+            # receiver congestion signalling
+            q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
+            if host_tc:
+                frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
+                sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
+                s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
+                                                sel > xoffQ)
+                pfc_any = s["pfc"].any(-2)
             else:
-                spilled = zero
-            pf = pf - (take - spilled)
-            budget_r = budget_r - take
-            new_q.append(qq - take)
-            drained = drained + take
-            pool_drained = pool_drained + (take - spilled)
-            fallback = fallback + spilled
-        s["qos_q"] = xp.stack(new_q, -2)
-        s["nic_dram"] = s["nic_dram"] + \
-            xp.where(jet, fallback, drained * 2.0 * miss)
-        s["mem_fb"] = s["mem_fb"] + fallback
-        strag_part = pool_drained * strag_share
-        parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
-                         -2)
-        s["ring"] = ring_set(s["ring"], it % H, parts)
-        s["resident"] = s["resident"] + pool_drained
-        s["strag_res"] = s["strag_res"] + strag_part
-        s["drained"] = s["drained"] + drained
-
-        idx = (it - p["d2"]) % H                  # [.., 2, R]
-        r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
-                                -3)[..., 0, :, :]
-        r2 = xp.where(it >= p["d2"], r2, zero)
-        for j, is_strag in ((0, False), (1, True)):
-            r = r2[..., j, :]
-            void = xp.minimum(r, s["esc_debt"])
-            s["esc_debt"] = s["esc_debt"] - void
-            r = r - void
-            repay = xp.minimum(void, s["repl_debt"])
-            s["repl_debt"] = s["repl_debt"] - repay
-            s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
-            s["resident"] = xp.maximum(zero, s["resident"] - r)
-            if is_strag:
-                s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
-
-        # Jet escape ladder (paper Algorithm 1)
-        avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-        esc_on = jet & (avail < p["safe"])
-        can_rep = s["repl_mem"] < p["mem_esc"]
-        x_rep = xp.where(esc_on & can_rep,
-                         xp.maximum(zero,
-                                    xp.minimum(s["strag_res"],
-                                               p["mem_esc"]
-                                               - s["repl_mem"])),
-                         zero)
-        s["resident"] = s["resident"] - x_rep
-        s["strag_res"] = s["strag_res"] - x_rep
-        s["esc_debt"] = s["esc_debt"] + x_rep
-        s["repl_debt"] = s["repl_debt"] + x_rep
-        s["repl_mem"] = s["repl_mem"] + x_rep
-        s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
-        s["replaces"] = s["replaces"] + (x_rep > zero)
-        x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
-        s["resident"] = s["resident"] - x_cop
-        s["strag_res"] = s["strag_res"] - x_cop
-        s["esc_debt"] = s["esc_debt"] + x_cop
-        s["esc_dram"] = s["esc_dram"] + x_cop
-        s["copies"] = s["copies"] + (x_cop > zero)
-        avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-        in_danger = esc_on & (avail2 < p["danger"])
-        s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt, s["ecn_tus"])
-        esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
-        s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
-        s["cnps"] = s["cnps"] + esc_fire
-        s["ecns"] = s["ecns"] + esc_fire
-        s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
-        s["pool_peak"] = xp.maximum(s["pool_peak"],
-                                    xp.where(jet, s["resident"], zero))
-
-        # receiver congestion signalling
-        q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
-        if host_tc:
-            frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
-            sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
-            s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
-                                            sel > xoffQ)
-            pfc_any = s["pfc"].any(-2)
-        else:
-            s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
-                                            q_frac > p["xoff"])
-            pfc_any = s["pfc"]
-        s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
-        cnp_tus = s["cnp_tus"] + fdt
-        wm_fire = wm_en & (q_frac > p["ecn_th"]) \
-            & (cnp_tus >= p["cnp_iv"])
-        s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
-        s["cnps"] = s["cnps"] + wm_fire
+                s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
+                                                q_frac > p["xoff"])
+                pfc_any = s["pfc"]
+            s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
+            cnp_tus = s["cnp_tus"] + fdt
+            wm_fire = wm_en & (q_frac > p["ecn_th"]) \
+                & (cnp_tus >= p["cnp_iv"])
+            s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
+            s["cnps"] = s["cnps"] + wm_fire
 
         # ---- 4. feedback routes back to the senders ----------------------- #
         share_cr = xp.where(arr_cr > zero,
@@ -2235,9 +2256,10 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             & (s["delivered"] + s["deliv_lo"] >= p["burst_done"]),
             now, s["completion"])
 
-        has_arr = arr_tot > zero
-        heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
-        s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
+        with _scope(xp, RECV_SCOPE):
+            has_arr = arr_tot > zero
+            heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
+            s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
         is_heavy = arangeF == s["heavy"][..., st["recv_of"]]
         f_esc = is_heavy & esc_fire[..., st["recv_of"]]
         f_wm = is_heavy & wm_fire[..., st["recv_of"]]

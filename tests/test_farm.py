@@ -317,3 +317,30 @@ def test_chunk_records_same_fields_on_every_path(tmp_path, monkeypatch):
     assert set(rec) == fields and rec["pack_wait_s"] == 0.0
     assert rec["pack_s"] > 0
     _assert_span_fields(rec)
+
+
+def test_chunk_records_count_receivers_and_flows(tmp_path, monkeypatch):
+    """Every chunk record carries its chunk's shape, receiving hosts (R)
+    and flows (F), on the in-process path and in a pool worker."""
+    from repro.fabric import farm as F
+    from repro.fabric import spans as S
+    scens = _grid(8)
+    fsp = FabricSweepParams.from_scenarios(scens)
+    assert (fsp.n_recv, fsp.n_flows) == (len(fsp.recv_hosts), 5)
+    farm = run_farm(scens, workers=0, chunk_size=4, backend="numpy",
+                    artifacts=False)
+    for rec in farm["manifest"]["records"]:
+        assert set(S.SHAPE_COUNTERS) <= set(rec)
+        assert (rec["recv_hosts"], rec["flows"]) == (fsp.n_recv,
+                                                     fsp.n_flows)
+    spec = GridSpec("incast", quick=True)
+    pool_scens, _ = spec.build()
+    env = FabricSweepParams.from_scenarios(pool_scens).envelope()
+    monkeypatch.setattr(F, "_WORKER", dict(
+        scens=pool_scens, sparse=False, envelope=env, backend="numpy",
+        rdir=str(tmp_path / "pool")))
+    rec = F._worker_run_chunk(chunk_plan(len(pool_scens), 8)[0])
+    want = FabricSweepParams.from_scenarios(pool_scens[:8])
+    assert (rec["recv_hosts"], rec["flows"]) == (want.n_recv,
+                                                 want.n_flows)
+    assert S.new_record(chunk=0)["recv_hosts"] == 0
