@@ -62,9 +62,11 @@ module is the firesim-style run-farm layer on top of it:
   the transfer counters ``h2d_arrays``, ``h2d_bytes``,
   ``d2h_arrays`` and ``d2h_bytes`` (buffers put on and pulled off the
   device: one or two each way), and the chunk's shape, ``recv_hosts``
-  (receiving hosts, R) and ``flows`` (F).  The manifest adds
+  (receiving hosts, R), ``flows`` (F) and ``wirings`` (distinct
+  topology wirings whose routes the pack derived; 1 when every point is
+  wired alike, 0 under dynamic routing).  The manifest adds
   ``envelope_s`` (``farm.envelope``: the full grid packed for its
-  envelope), ``plan_s`` (``farm.plan``: the
+  envelope) with that pack's ``wirings``, ``plan_s`` (``farm.plan``: the
   chunk plan and the grid's fingerprint) and ``merge_s``
   (``farm.merge``: the merged table, and the shard writes of
   in-process chunks).  Spans on the
@@ -157,7 +159,8 @@ def _pack_chunk(scens: Sequence, entry: dict, sparse: bool,
         padded, n_real = _pad_chunk(scens, entry)
         fsp = V.FabricSweepParams.from_scenarios(padded, sparse=sparse,
                                                  envelope=envelope)
-    record.update(recv_hosts=fsp.n_recv, flows=fsp.n_flows)
+    record.update(recv_hosts=fsp.n_recv, flows=fsp.n_flows,
+                  wirings=fsp.wirings)
     return fsp, n_real
 
 
@@ -365,6 +368,7 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
             "envelope": {k: (bool(v) if isinstance(v, (bool, np.bool_))
                              else int(v)) for k, v in envelope.items()},
             "structure_key": full.structure_key,
+            "wirings": full.wirings,
             "config_hash": fingerprint, "git_sha": A.git_sha(),
             "workers": workers, "records": (prev or {}).get("records",
                                                             []),
@@ -372,7 +376,8 @@ def run_farm(grid: Union[str, GridSpec, Sequence],
         A.write_manifest(rdir, manifest)
     else:
         manifest = {"run_id": run_id or "<in-memory>",
-                    "status": "running", "records": []}
+                    "status": "running", "wirings": full.wirings,
+                    "records": []}
 
     todo = [e["chunk"] for e in plan if e["chunk"] not in set(done)]
     t0 = time.perf_counter()

@@ -11,8 +11,9 @@
 
 :func:`transfer` is a span over a host-device transfer that also counts
 the arrays and bytes it moves (``h2d_arrays``, ``h2d_bytes``, ...).
-A record also counts the chunk's receiving hosts and flows
-(``recv_hosts``, ``flows``).
+A record also counts the chunk's receiving hosts, flows and the distinct
+wirings whose routes its pack derived (``recv_hosts``, ``flows``,
+``wirings``).
 Outside the profiler a span costs about a microsecond.
 """
 from __future__ import annotations
@@ -32,8 +33,9 @@ RUN_SPANS = ("farm.envelope", "farm.plan", "farm.merge")
 #: Counters of a chunk's transfers.
 TRANSFER_COUNTERS = ("h2d_arrays", "h2d_bytes", "d2h_arrays", "d2h_bytes")
 #: Counters of a chunk's shape, set when it is packed: receiving hosts
-#: (R) and flows (F), to read per-chunk numbers per receiver or per flow.
-SHAPE_COUNTERS = ("recv_hosts", "flows")
+#: (R) and flows (F), to read per-chunk numbers per receiver or per flow,
+#: and the distinct topology wirings whose routes the pack derived.
+SHAPE_COUNTERS = ("recv_hosts", "flows", "wirings")
 
 
 def field(name: str) -> str:

@@ -261,9 +261,10 @@ class Topology:
         names = self.hosts + self.leaves + self.spines + self.super_spines
         if len(set(names)) != len(names):
             raise ValueError("node names must be unique")
+        leaf_set = set(self.leaves)
         for h in self.hosts:
             leaf = self.host_leaf.get(h)
-            if leaf not in self.leaves:
+            if leaf not in leaf_set:
                 raise ValueError(f"host {h} not attached to a leaf")
             if (h, leaf) not in self.links or (leaf, h) not in self.links:
                 raise ValueError(f"host {h} missing bidirectional access "
@@ -279,14 +280,20 @@ class Topology:
         # multi-pod fabrics) — candidate sets are wiring-restricted and
         # route() raises on unroutable pairs.  Structurally we only
         # require each fabric switch to be wired at all.
+        # One pass over the links collects the nodes some leaf feeds and
+        # the nodes some spine feeds; each switch is then a set lookup.
         spine_set, ss_set = set(self.spines), set(self.super_spines)
+        fed_by_leaf, fed_by_spine = set(), set()
+        for l in self.links.values():
+            if l.src in leaf_set:
+                fed_by_leaf.add(l.dst)
+            if l.src in spine_set:
+                fed_by_spine.add(l.dst)
         for s in self.spines:
-            if not any(l.dst == s and l.src in self.leaves
-                       for l in self.links.values()):
+            if s not in fed_by_leaf:
                 raise ValueError(f"spine {s} not connected to any leaf")
         for ss in self.super_spines:
-            if not any(l.dst == ss and l.src in spine_set
-                       for l in self.links.values()):
+            if ss not in fed_by_spine:
                 raise ValueError(f"super-spine {ss} not connected to any "
                                  "spine")
         if ss_set and not spine_set:

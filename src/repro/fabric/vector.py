@@ -178,6 +178,16 @@ _SWITCH_TC = [
 ]
 
 
+def _same_wiring(a, b) -> bool:
+    """Whether topologies ``a`` and ``b`` give every flow the same
+    :meth:`~repro.fabric.topology.Topology.route`: it reads only host
+    attachment, the spine and super-spine lists (their order picks the
+    ECMP path) and which links exist, never rates or failure schedules."""
+    return a is b or (a.host_leaf == b.host_leaf and a.spines == b.spines
+                      and a.super_spines == b.super_spines
+                      and a.links.keys() == b.links.keys())
+
+
 @dataclasses.dataclass
 class FabricSweepParams:
     """Static fabric structure + stacked per-point parameters.
@@ -246,6 +256,9 @@ class FabricSweepParams:
     # extra pause pairs, plus the candidate hop ports for n_pausable.
     pause_extra: Optional[np.ndarray] = None
     pausable_extra: Optional[np.ndarray] = None
+    # distinct wirings whose routes the static-path check derived (1 when
+    # every point is wired like point 0; 0 on the dynamic-routing branch)
+    wirings: int = 0
 
     def envelope(self) -> dict:
         """Chunk-boundary envelope of this packing: the capability
@@ -379,15 +392,25 @@ class FabricSweepParams:
                 raise ValueError("grid points must share the flow set "
                                  "(src/dst/tag/qos); offered/burst/start "
                                  "may vary")
+        wirings = 0
         if not dyn:
-            # static fast path: routes are frozen structure and must agree
+            # static fast path: routes are frozen structure and must
+            # agree.  Topology.route reads only the wiring, so a point
+            # wired like one already checked has the same routes by
+            # construction; each new wiring re-derives every flow's route
             routes = [topo0.route(f.src, f.dst, fid)
                       for fid, f in enumerate(flows0)]
+            checked = [topo0]
             for s in scens:
-                if any(s.topology.route(f.src, f.dst, fid) != routes[fid]
+                tt = s.topology
+                if any(_same_wiring(tt, w) for w in checked):
+                    continue
+                if any(tt.route(f.src, f.dst, fid) != routes[fid]
                        for fid, f in enumerate(s.flows)):
                     raise ValueError("grid points must share routes (same "
                                      "topology structure)")
+                checked.append(tt)
+            wirings = len(checked)
         else:
             # dynamic-routing land: routes are per-tick state, so only the
             # node/link *structure* must agree; routing mode and failure
@@ -841,7 +864,7 @@ class FabricSweepParams:
                    sparse=sparse, port_of=port_of, prv_port=prv_port,
                    nxt_slot=nxt_slot, pack_fail=pack_fail,
                    pause_extra=pause_extra,
-                   pausable_extra=pausable_extra)
+                   pausable_extra=pausable_extra, wirings=wirings)
 
 
 # --------------------------------------------------------------------------- #
