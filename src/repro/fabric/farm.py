@@ -132,12 +132,9 @@ def _resolve_grid(grid, quick: bool, overrides: Optional[dict]
     return scens, [{} for _ in scens], None
 
 
-def _pick_sparse(scens: Sequence, incidence: str) -> bool:
-    if incidence not in ("auto", "dense", "sparse"):
-        raise ValueError(f"unknown incidence {incidence!r}")
-    return incidence == "sparse" or (
-        incidence == "auto"
-        and any(bool(s.topology.super_spines) for s in scens))
+# the engine choice lives in vector; bench/readings.py and the tests
+# import it from here under this name
+_pick_sparse = V.pick_sparse
 
 
 def _pad_chunk(scens: Sequence, entry: dict) -> Tuple[List, int]:
@@ -177,14 +174,7 @@ def _execute_packed(fsp, n_real: int, backend: str, unroll,
     """Run one packed chunk (on ``device`` when given), slice off
     padding, count compiles; spans are timed into ``record``."""
     c0 = V.PROGRAM_COMPILES
-    if backend == "numpy":
-        out = V._run_numpy(fsp)
-    elif backend == "jax":
-        from . import fused
-        out = V._run_jax(fsp, unroll, fused.resolve_impl("auto"), device,
-                         record)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+    out = V.run_packed(fsp, backend, unroll, device=device, record=record)
     with span("chunk.unpack", record):
         out = {k: np.asarray(v)[:n_real] for k, v in out.items()}
     return out, V.PROGRAM_COMPILES - c0

@@ -64,6 +64,7 @@ import contextlib
 import dataclasses
 import functools
 import hashlib
+import types
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -884,6 +885,464 @@ def _contractions(xp):
             functools.partial(xp.einsum, precision=hi))
 
 
+# --------------------------------------------------------------------------- #
+# Layout-free tick stages, shared by the dense and the sparse tick
+# --------------------------------------------------------------------------- #
+# Each engine calls them in its own stage order.  They read per-flow,
+# per-receiver and per-(TC, port) arrays, never the queue layout
+# ([2, P, F] dense, [2, S, F] sparse), so one copy serves both engines.
+# They update the step's own state dict ``s`` in place.
+def _tick_consts(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int,
+                 o: dict) -> types.SimpleNamespace:
+    """The loop-invariant per-point quantities of a tick, computed once
+    outside the scan (per-op dispatch dominates the CPU backend at these
+    shapes), with the delay rings' writer and lengths and the trace-time
+    flags of ``o`` the shared stages branch on."""
+    f = dtype
+    c = types.SimpleNamespace(
+        dtype=dtype, ring_set=ring_set, H=H, Hc=Hc,
+        wrr=o.get("wrr", False), host_tc=o.get("host_tc", False),
+        any_cc=o.get("cc", False),
+        # fused-kernel tier for the two priority water-fills ("ref" is
+        # the inline formulation; "pallas"/"interpret" need jnp)
+        impl=o.get("impl", "ref") if xp is not np else "ref")
+    c.bpt = f(1e9 / 8.0 * dt * 1e-6)       # bytes per (Gbps * tick)
+    c.fdt = f(dt)
+    c.zero, c.one, c.tiny = f(0.0), f(1.0), f(1e-30)
+    c.half, c.inf = f(0.5), f(np.inf)
+    c.eps_q = f(1e-9)
+    c.fold_at = f(65536.0)
+    c.arangeF = xp.arange(st["recv_of"].shape[0], dtype=xp.int32)
+    c.budget = p["gbps"] * c.bpt
+    c.budget_crumb = c.budget * f(1e-6)
+    # switch traffic classes: the per-TC knee/watermark thresholds
+    # broadcast as [.., Q, 1] against [.., Q, P]
+    c.buf_tc = p["buf"][..., None, None]
+    c.kmin_th = p["kmin"][..., None] * c.buf_tc
+    c.ecn_on = p["ecn_en"] > 0.5
+    c.can_assert = p["can_assert"] > 0.5
+    c.sxoff = p["sw_xoff"][..., None]
+    c.sxon = p["sw_xon"][..., None]
+    # on-off burst trains: sources offer only while the duty-cycle phase
+    # is inside the on-window (off_us == 0 means always on)
+    c.onoff = p["off_us"] > c.zero
+    c.period = xp.where(c.onoff, p["on_us"] + p["off_us"], c.one)
+    c.jet = p["jet"] > 0.5
+    c.avail_dram = xp.maximum(c.zero, p["membw"] - p["cpu_bw"])
+    c.jet_cap = xp.minimum(p["pcie"], p["line1"] * 4.0) * c.bpt
+    c.strag_share = xp.where(c.jet, p["sfrac"], c.zero)
+    c.inv_knee = c.one / (p["knee"] * p["ddio"])
+    c.rx_pfc_en = p["pfc_en"] > 0.5
+    c.wm_en = p["wm_cnp"] > 0.5
+    c.linecap = xp.minimum(p["line"], p["cap"])
+    if c.wrr:
+        c.quantaQ = p["quanta"][..., None]            # [.., Q, 1]
+        c.is_wrr = (p["sched"] == 1)[..., None, None]  # [.., 1, 1]
+    if c.host_tc:
+        c.hpfc_b = (p["hpfc"] > c.half)[..., None, :]   # [.., 1, R]
+        c.rx_pfc_tc = c.rx_pfc_en[..., None, :]
+        c.xoffQ = p["xoff"][..., None, :]
+        c.xonQ = p["xon"][..., None, :]
+    if o.get("dyn", False) and o.get("Sn", 0):
+        # dense dynamic routing's spine choice (the sparse tick has none)
+        c.bufSF = p["buf"][..., None, None]           # vs [.., S, F]
+        c.hystF = p["hystb"][..., None]               # vs [.., F]
+        c.arangeS = xp.arange(o["Sn"], dtype=xp.int32)[:, None]
+    if c.any_cc:
+        # algorithm lanes (CcConfig.code: 0 dcqcn, 1 timely, 2 hpcc)
+        c.is_dcqcn = p["cc_algo"] == 0
+        c.timely_m = p["cc_algo"] == 1
+        c.hpcc_m = p["cc_algo"] == 2
+        c.inv_brtt = c.one / p["base_rtt"]            # [.., F]
+        c.u_floor = f(0.01)
+    return c
+
+
+def _fold(xp, c, s, hi, lo):
+    """Drain a split accumulator's low part into its high part once it
+    outgrows 64 KiB.  Keeping per-tick increments on a small-magnitude
+    accumulator bounds float32 rounding drift to O(10) bytes over a
+    run — tight enough that closed-flow completion thresholds stay
+    meaningful — while costing three element-wise ops per tick."""
+    full = xp.abs(s[lo]) >= c.fold_at
+    s[hi] = s[hi] + xp.where(full, s[lo], c.zero)
+    s[lo] = xp.where(full, c.zero, s[lo])
+
+
+def _grants(xp, c, qtc, can_q, budget0):
+    """Per-(TC, port) drain fractions [.., Q, P] of a stage's ports from
+    their class totals ``qtc`` and unpaused backlog ``can_q``: strict
+    priority as one fused water-fill stage — each class takes
+    min(1, left/demand), leftover budget below 1e-6 of the link budget
+    clamps to zero (rounding crumbs after a class eats the whole budget
+    must not become micro-byte trickles for the next class — they would
+    trigger full-size discrete CNPs downstream); relative, so f32 and f64
+    backends agree with the scalar driver on every grant/no-grant
+    decision (OutputPort.drain).  The ref tier is op for op the unrolled
+    loop it replaced; pallas/interpret run the VMEM kernel.  Points that
+    schedule WRR water-fill instead."""
+    zero, one, tiny = c.zero, c.one, c.tiny
+    frac_q = fused.priority_grants(
+        xp, qtc, can_q if c.impl == "ref"
+        else xp.where(can_q, one, zero),
+        budget0, c.budget_crumb, one, zero, impl=c.impl)
+    if c.wrr:
+        # weighted water-filling over backlogged unpaused classes,
+        # unrolled Q rounds with the exact op order of
+        # OutputPort._wrr_fracs (float64 reference == scalar driver)
+        rem = xp.where(can_q, qtc, zero)
+        alloc = xp.zeros_like(qtc)
+        bl = budget0
+        for _ in range(N_QOS):
+            wq = xp.where(rem > zero, c.quantaQ, zero)
+            wsum = wq.sum(-2)                 # [.., P]
+            share = bl[..., None, :] * wq \
+                / xp.maximum(wsum, tiny)[..., None, :]
+            take = xp.minimum(share, rem)
+            alloc = alloc + take
+            rem = rem - take
+            bl = bl - take.sum(-2)
+            bl = xp.where(bl < c.budget_crumb, zero, bl)
+        frac_wrr = xp.where(qtc > zero,
+                            alloc / xp.maximum(qtc, tiny), zero)
+        frac_q = xp.where(c.is_wrr, frac_wrr, frac_q)
+    return frac_q
+
+
+def _link_windows(xp, c, p, t, flap: bool):
+    """Stage 0's per-port link state at tick ``t``: ``(up, fail)``
+    [.., P], 1.0 where the link is up this tick and where it goes down
+    this very tick.  ``flap`` folds the periodic flaps into the same
+    masks (Topology.flap_ticks: down for the first `down` ticks of each
+    `period` cycle from `start`)."""
+    downP = (t >= p["fail_at"]) & (t < p["fail_until"])   # [.., P]
+    edgeP = t == p["fail_at"]
+    if flap:
+        since = t - p["flap_start"]
+        live = t >= p["flap_start"]
+        downP = downP | (live
+                         & (since % p["flap_period"]
+                            < p["flap_down"]))
+        edgeP = edgeP | (live & (since % p["flap_period"] == 0))
+    return xp.where(downP, c.zero, c.one), xp.where(edgeP, c.one, c.zero)
+
+
+def _send_rate(xp, c, p, s, now):
+    """Stage 1's rate machine: advance each flow's DCQCN timers and rate,
+    and return what the flow offers this tick [.., F] — its rate, capped
+    by line rate, the rest of its burst and the burst train's on-phase."""
+    zero, fdt, bpt = c.zero, c.fdt, c.bpt
+    adv = now > p["start"]
+    # the DCQCN timer machinery only moves DCQCN-lane flows; the CC
+    # block after forwarding writes the timely/hpcc rates instead
+    dadv = (adv & c.is_dcqcn) if c.any_cc else adv
+    adv_dt = xp.where(dadv, fdt, zero)
+    a_tus = s["a_tus"] + adv_dt
+    a_fire = dadv & (a_tus >= p["a_tmr"])
+    s["alpha"] = xp.where(a_fire, (1.0 - p["g"]) * s["alpha"],
+                          s["alpha"])
+    s["a_tus"] = xp.where(a_fire, zero, a_tus)
+    t_us = s["t_us"] + adv_dt
+    byts = xp.where(dadv, s["byts"] + s["rc"] * bpt, s["byts"])
+    t_fire = dadv & (t_us >= p["r_tmr"])
+    s["t_stage"] = s["t_stage"] + t_fire
+    s["t_us"] = xp.where(t_fire, zero, t_us)
+    b_fire = dadv & (byts >= p["bctr"])
+    s["b_stage"] = s["b_stage"] + b_fire
+    s["byts"] = xp.where(b_fire, zero, byts)
+    fired = t_fire | b_fire
+    stage = xp.minimum(s["t_stage"], s["b_stage"])
+    s["rt"] = xp.where(fired & (stage == p["fth"]),
+                       xp.minimum(p["dline"], s["rt"] + p["ai"]),
+                       s["rt"])
+    s["rt"] = xp.where(fired & (stage > p["fth"]),
+                       xp.minimum(p["dline"], s["rt"] + p["hai"]),
+                       s["rt"])
+    s["rc"] = xp.where(fired,
+                       xp.minimum(p["dline"],
+                                  0.5 * (s["rc"] + s["rt"])),
+                       s["rc"])
+
+    gbps = xp.minimum(s["rc"], c.linecap)
+    room = xp.maximum(p["burst"] - (s["injected"] + s["inj_lo"]), zero)
+    # burst-train duty cycle: the DCQCN machine keeps running, the
+    # tap only opens during the on-phase (matches SenderHost.offer)
+    active = adv & (~c.onoff | (xp.fmod(now - p["start"], c.period)
+                                < p["on_us"]))
+    return xp.where(active, xp.minimum(gbps * bpt, room), zero)
+
+
+def _cc_update(xp, c, p, s, legs):
+    """Stage 2.2: delay/INT telemetry -> CC zoo rate updates.  ``legs``
+    yields each hop's end-of-forwarding queue, drained bytes and budget
+    along each flow's path as ``(q, tx, budget)`` [.., F] triples, in
+    path order; they fold into rtt = base + sum(q/budget) and util = max
+    per-hop (txRate/B + qlen/(B*T)) — run_fabric's loop as masked lanes.
+    A hop with zero budget drops out."""
+    zero, one, tiny, fdt = c.zero, c.one, c.tiny, c.fdt
+    qd = zero
+    util = zero
+    for q_l, tx_l, b_l in legs:
+        ok = b_l > zero
+        qd = qd + xp.where(ok, q_l / xp.maximum(b_l, tiny), zero)
+        u_l = xp.where(ok, (tx_l + q_l * (fdt * c.inv_brtt))
+                       / xp.maximum(b_l, tiny), zero)
+        util = xp.maximum(util, u_l)
+    rtt = p["base_rtt"] + qd * fdt
+    ctus = s["cc_tus"] + fdt
+    fire = ctus >= p["cc_upd"]
+    s["cc_tus"] = xp.where(fire, zero, ctus)
+    # Timely: smoothed RTT gradient picks the branch
+    ft = fire & c.timely_m
+    diff = rtt - s["prev_rtt"]
+    rd_new = (1.0 - p["tl_a"]) * s["rtt_diff"] + p["tl_a"] * diff
+    s["prev_rtt"] = xp.where(ft, rtt, s["prev_rtt"])
+    s["rtt_diff"] = xp.where(ft, rd_new, s["rtt_diff"])
+    grad = rd_new * c.inv_brtt
+    rc = s["rc"]
+    r_tim = xp.where(
+        rtt < p["t_low"], rc + p["tl_add"],
+        xp.where(rtt > p["t_high"],
+                 rc * (one - p["tl_beta"]
+                       * (one - p["t_high"] / rtt)),
+                 xp.where(grad <= zero, rc + p["tl_add"],
+                          rc * xp.maximum(
+                              zero, one - p["tl_beta"] * grad))))
+    rc_tim = xp.minimum(p["line"],
+                        xp.maximum(p["cc_minr"], r_tim))
+    # HPCC: drive max per-hop utilization toward eta
+    fh = fire & c.hpcc_m
+    mult = xp.clip(p["hp_eta"] / xp.maximum(util, c.u_floor),
+                   c.half, c.dtype(2.0))
+    rc_hp = xp.minimum(p["line"],
+                       xp.maximum(p["cc_minr"],
+                                  rc * mult + p["hp_ai"]))
+    s["rc"] = xp.where(ft, rc_tim, xp.where(fh, rc_hp, rc))
+
+
+def _receive(xp, c, st, p, s, arr_b, t, now, it, flt: bool):
+    """Stage 3: the receivers advance one tick (HostDatapath, stacked)
+    on this tick's per-flow arrivals ``arr_b`` [.., F].  Returns what
+    stage 4 reads: ``(arr_rb, arr_cr, arr_tot, acc_cr, esc_fire,
+    wm_fire)``.  ``flt`` stamps the crash-recovery latency (fault
+    layer, dense tick only)."""
+    zero, one, tiny, fdt, jet = c.zero, c.one, c.tiny, c.fdt, c.jet
+    with _scope(xp, RECV_SCOPE):
+        arr_rb = st["recv_onehot"] * arr_b[..., None, :]
+        # QoS-classed arrivals [.., Q, R] (admission class x receiver)
+        arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
+        arr_tot = arr_cr.sum(-2)
+        # admission: RNIC buffer space granted in QoS-priority order —
+        # the second fused priority water-fill (HostDatapath.admit_link)
+        space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
+        acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=c.impl)
+        accepted = acc_cr[..., 0, :]
+        for q_i in range(1, N_QOS):
+            accepted = accepted + acc_cr[..., q_i, :]
+        if flt:
+            # first byte accepted after a crash restart stamps the
+            # crash-recovery latency (run_fabric step 3)
+            rec_hit = (t >= p["crash_until"]) & (accepted > zero) \
+                & xp.isinf(s["crash_rec"])
+            s["crash_rec"] = xp.where(
+                rec_hit, now - p["crash_at"].astype(c.dtype) * fdt,
+                s["crash_rec"])
+        s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
+        s["qos_q"] = s["qos_q"] + acc_cr
+
+        ws = p["qp_bytes"] + s["resident"]
+        miss = xp.clip((ws - p["ddio"]) * c.inv_knee, zero, one)
+        s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
+        ddio_bw = xp.where(miss > 1e-9,
+                           xp.minimum(p["pcie"],
+                                      c.avail_dram / (2.0 * miss + tiny)),
+                           p["pcie"])
+        # drain budget granted in QoS-priority order; under Jet pool
+        # pressure (< cache_safe free) the LOW class spills to DRAM (§5)
+        budget = xp.where(jet, c.jet_cap, ddio_bw * c.bpt)
+        pool_free = xp.maximum(zero, p["pool"] - s["resident"])
+        spill = jet & (pool_free / p["pool"] < p["safe"])
+        pf = xp.where(jet, pool_free, c.inf)
+        drained = pool_drained = fallback = zero
+        new_q = []
+        for q_i in range(N_QOS):
+            qq = s["qos_q"][..., q_i, :]
+            take = xp.minimum(xp.minimum(qq, budget), pf)
+            if q_i == N_QOS - 1:        # LOW spills instead of waiting
+                take = xp.where(spill, xp.minimum(qq, budget), take)
+                spilled = xp.where(spill, take, zero)
+            else:
+                spilled = zero
+            pf = pf - (take - spilled)
+            budget = budget - take
+            new_q.append(qq - take)
+            drained = drained + take
+            pool_drained = pool_drained + (take - spilled)
+            fallback = fallback + spilled
+        s["qos_q"] = xp.stack(new_q, -2)
+        s["nic_dram"] = s["nic_dram"] + \
+            xp.where(jet, fallback, drained * 2.0 * miss)
+        s["mem_fb"] = s["mem_fb"] + fallback
+        strag_part = pool_drained * c.strag_share
+        parts = xp.stack([pool_drained * (1.0 - c.strag_share), strag_part],
+                         -2)
+        # ring layout [H, 2, R]: the write is a contiguous leading-axis
+        # slice update, which XLA aliases in place inside the scan carry
+        s["ring"] = c.ring_set(s["ring"], it % c.H, parts)
+        s["resident"] = s["resident"] + pool_drained
+        s["strag_res"] = s["strag_res"] + strag_part
+        s["drained"] = s["drained"] + drained
+
+        idx = (it - p["d2"]) % c.H                # [.., 2, R]
+        r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
+                                -3)[..., 0, :, :]
+        r2 = xp.where(it >= p["d2"], r2, zero)
+        for j, is_strag in ((0, False), (1, True)):
+            r = r2[..., j, :]
+            void = xp.minimum(r, s["esc_debt"])
+            s["esc_debt"] = s["esc_debt"] - void
+            r = r - void
+            repay = xp.minimum(void, s["repl_debt"])
+            s["repl_debt"] = s["repl_debt"] - repay
+            s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
+            s["resident"] = xp.maximum(zero, s["resident"] - r)
+            if is_strag:
+                s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
+
+        # Jet escape ladder (paper Algorithm 1)
+        avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+        esc_on = jet & (avail < p["safe"])
+        can_rep = s["repl_mem"] < p["mem_esc"]
+        x_rep = xp.where(esc_on & can_rep,
+                         xp.maximum(zero,
+                                    xp.minimum(s["strag_res"],
+                                               p["mem_esc"]
+                                               - s["repl_mem"])),
+                         zero)
+        s["resident"] = s["resident"] - x_rep
+        s["strag_res"] = s["strag_res"] - x_rep
+        s["esc_debt"] = s["esc_debt"] + x_rep
+        s["repl_debt"] = s["repl_debt"] + x_rep
+        s["repl_mem"] = s["repl_mem"] + x_rep
+        s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
+        s["replaces"] = s["replaces"] + (x_rep > zero)
+        x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
+        s["resident"] = s["resident"] - x_cop
+        s["strag_res"] = s["strag_res"] - x_cop
+        s["esc_debt"] = s["esc_debt"] + x_cop
+        s["esc_dram"] = s["esc_dram"] + x_cop
+        s["copies"] = s["copies"] + (x_cop > zero)
+        avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
+        in_danger = esc_on & (avail2 < p["danger"])
+        s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt,
+                                s["ecn_tus"])
+        esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
+        s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
+        s["cnps"] = s["cnps"] + esc_fire
+        s["ecns"] = s["ecns"] + esc_fire
+        s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
+        s["pool_peak"] = xp.maximum(s["pool_peak"],
+                                    xp.where(jet, s["resident"], zero))
+
+        # receiver congestion signalling
+        q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
+        if c.host_tc:
+            # per-class receiver gate ([.., Q, R] pause state): per-TC
+            # points watermark each class's occupancy of its 1/N_QOS
+            # buffer partition (ReceiverHost's arithmetic, op for op),
+            # legacy points see the total occupancy in every row —
+            # identical decisions to the scalar whole-link gate
+            frac_c = s["qos_q"] / (p["rnic_buf"]
+                                   / c.dtype(N_QOS))[..., None, :]
+            sel = xp.where(c.hpfc_b, frac_c, q_frac[..., None, :])
+            s["pfc"] = c.rx_pfc_tc & xp.where(s["pfc"], sel >= c.xonQ,
+                                              sel > c.xoffQ)
+            pfc_any = s["pfc"].any(-2)
+        else:
+            s["pfc"] = c.rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
+                                              q_frac > p["xoff"])
+            pfc_any = s["pfc"]
+        s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
+        cnp_tus = s["cnp_tus"] + fdt
+        wm_fire = c.wm_en & (q_frac > p["ecn_th"]) \
+            & (cnp_tus >= p["cnp_iv"])
+        s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
+        s["cnps"] = s["cnps"] + wm_fire
+    return arr_rb, arr_cr, arr_tot, acc_cr, esc_fire, wm_fire
+
+
+def _feedback(xp, c, st, p, s, arr_b, arr_m, rx, now, it, ledger=None):
+    """Stage 4's receiver half: delivery and completion from stage 3's
+    admission (``rx``, what :func:`_receive` returned), the receivers'
+    CNP target, NP pacing of switch ECN marks ``arr_m`` [.., F] and the
+    CNP ring's cuts of the senders' DCQCN rate.  RNIC tail drops re-open
+    the sender's tap, or go to the fault layer's ``ledger`` when one is
+    given.  Returns the bytes delivered this tick [.., F]."""
+    zero, one, tiny, half, fdt = c.zero, c.one, c.tiny, c.half, c.fdt
+    arr_rb, arr_cr, arr_tot, acc_cr, esc_fire, wm_fire = rx
+    # per-class acceptance share: a flow recovers the share its own
+    # admission class received (matches HostDatapath.admit_link)
+    share_cr = xp.where(arr_cr > zero,
+                        acc_cr / xp.maximum(arr_cr, tiny), zero)
+    deliv = arr_b * share_cr[..., st["cls_of"], st["recv_of"]]
+    s["deliv_lo"] = s["deliv_lo"] + deliv
+    # RNIC tail drops are retransmitted too (fluid RC / the ledger)
+    if ledger is not None:
+        ledger(s, arr_b - deliv)
+    else:
+        s["inj_lo"] = s["inj_lo"] - (arr_b - deliv)
+    s["completion"] = xp.where(
+        xp.isinf(s["completion"])
+        & (s["delivered"] + s["deliv_lo"] >= p["burst_done"]),
+        now, s["completion"])
+
+    # receiver CNPs hit the heaviest recently-arriving flow (lowest
+    # flow id on ties); with nothing arriving the previous target
+    # stays throttled, as in run_fabric/run_sim
+    with _scope(xp, RECV_SCOPE):
+        has_arr = arr_tot > zero
+        heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
+        s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
+    is_heavy = c.arangeF == s["heavy"][..., st["recv_of"]]
+    f_esc = is_heavy & esc_fire[..., st["recv_of"]]
+    f_wm = is_heavy & wm_fire[..., st["recv_of"]]
+    # switch ECN marks -> per-flow CNPs, paced per DCQCN NP
+    s["backlog"] = s["backlog"] + arr_m
+    pace_tus = s["pace_tus"] + fdt
+    pace_fire = (s["backlog"] > zero) & (pace_tus >= p["cnp_iv_f"])
+    s["pace_tus"] = xp.where(pace_fire, zero, pace_tus)
+    s["backlog"] = xp.where(pace_fire, zero, s["backlog"])
+    # CNP propagation ring [Hc, 3, F]: notifications generated this
+    # tick (slot t % Hc) cut their sender its *own* cnp_delay ticks
+    # later — the delay is per flow, so the read index is a [F]
+    # gather (slot (t - delay_f) % Hc; Hc > every delay, so for
+    # t < delay the read lands on a slot not yet written, which
+    # still holds zero)
+    fires = xp.stack([xp.where(f_esc, one, zero),
+                      xp.where(f_wm, one, zero),
+                      xp.where(pace_fire, one, zero)], -2)
+    s["cring"] = c.ring_set(s["cring"], it % c.Hc, fires)
+    cidx = (it - p["cnp_dly"]) % c.Hc
+    due = xp.take_along_axis(s["cring"], cidx[..., None, None, :],
+                             -3)[..., 0, :, :]
+    for j in range(3):
+        fire = due[..., j, :] > half
+        if c.any_cc:
+            # timely/hpcc ignore CNPs (CongestionControl.on_cnp)
+            fire = fire & c.is_dcqcn
+        # DCQCN on_cnp for flows where ``fire`` holds
+        s["rt"] = xp.where(fire, s["rc"], s["rt"])
+        s["rc"] = xp.where(
+            fire, xp.maximum(p["minr"], s["rc"] * (1.0 - s["alpha"] / 2.0)),
+            s["rc"])
+        s["alpha"] = xp.where(
+            fire, xp.minimum(one, (1.0 - p["g"]) * s["alpha"] + p["g"]),
+            s["alpha"])
+        for k in ("t_us", "byts", "t_stage", "b_stage", "a_tus"):
+            s[k] = xp.where(fire, zero, s[k])
+    return deliv
+
+
 def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                opts: Optional[dict] = None):
     """Build ``step(state, t) -> state`` in array namespace ``xp``.
@@ -908,69 +1367,17 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
     program, so static grids stay bit-identical and pay nothing.
     """
     o = opts or {}
-    dyn, wrr = o.get("dyn", False), o.get("wrr", False)
-    host_tc, Hs = o.get("host_tc", False), o.get("Hs", 1)
-    Sn = o.get("Sn", 0)
+    dyn, Sn, Hs = o.get("dyn", False), o.get("Sn", 0), o.get("Hs", 1)
     any_cc, any_msg = o.get("cc", False), o.get("msg", False)
     Lm = o.get("Lm", 1)
     flt, flap = o.get("flt", False), o.get("flap", False)
-    # fused-kernel tier for the two priority water-fills ("ref" is the
-    # inline formulation; "pallas"/"interpret" need the jnp namespace)
-    impl = o.get("impl", "ref") if xp is not np else "ref"
     mm, es = _contractions(xp)
     f = dtype
-    bpt = f(1e9 / 8.0 * dt * 1e-6)       # bytes per (Gbps * tick)
-    fdt = f(dt)
-    zero, one, tiny = f(0.0), f(1.0), f(1e-30)
-    half, inf = f(0.5), f(np.inf)
-    eps_q = f(1e-9)
-    arangeF = xp.arange(st["recv_of"].shape[0], dtype=xp.int32)
-    # loop-invariant per-point quantities, computed once outside the scan
-    budget = p["gbps"] * bpt
-    budget_crumb = budget * f(1e-6)
-    budgetP = budget                     # step() shadows `budget` locally
-    buf = p["buf"][..., None]
+    c = _tick_consts(xp, ring_set, st, p, dt, H, dtype, Hc, o)
+    zero, one, tiny, half, fdt = c.zero, c.one, c.tiny, c.half, c.fdt
     # switch traffic classes: clsF is the per-point [Q, F] flow->TC
-    # one-hot (all flows on TC 0 for legacy per-link points); the per-TC
-    # knee/watermark thresholds broadcast as [.., Q, 1] against [.., Q, P]
+    # one-hot (all flows on TC 0 for legacy per-link points)
     clsF = p["clsF"]
-    buf_tc = p["buf"][..., None, None]
-    kmin_th = p["kmin"][..., None] * buf_tc
-    ecn_on = p["ecn_en"] > 0.5
-    can_assert = p["can_assert"] > 0.5
-    sxoff = p["sw_xoff"][..., None]
-    sxon = p["sw_xon"][..., None]
-    # on-off burst trains: sources offer only while the duty-cycle phase
-    # is inside the on-window (off_us == 0 means always on)
-    onoff = p["off_us"] > zero
-    period = xp.where(onoff, p["on_us"] + p["off_us"], one)
-    jet = p["jet"] > 0.5
-    avail_dram = xp.maximum(zero, p["membw"] - p["cpu_bw"])
-    jet_cap = xp.minimum(p["pcie"], p["line1"] * 4.0) * bpt
-    strag_share = xp.where(jet, p["sfrac"], zero)
-    inv_knee = one / (p["knee"] * p["ddio"])
-    rx_pfc_en = p["pfc_en"] > 0.5
-    wm_en = p["wm_cnp"] > 0.5
-    linecap = xp.minimum(p["line"], p["cap"])
-    if wrr:
-        quantaQ = p["quanta"][..., None]            # [.., Q, 1]
-        is_wrr = (p["sched"] == 1)[..., None, None]  # [.., 1, 1]
-    if host_tc:
-        hpfc_b = (p["hpfc"] > half)[..., None, :]   # [.., 1, R]
-        rx_pfc_tc = rx_pfc_en[..., None, :]
-        xoffQ = p["xoff"][..., None, :]
-        xonQ = p["xon"][..., None, :]
-    if dyn and Sn:
-        bufSF = p["buf"][..., None, None]           # vs [.., S, F]
-        hystF = p["hystb"][..., None]               # vs [.., F]
-        arangeS = xp.arange(Sn, dtype=xp.int32)[:, None]
-    if any_cc:
-        # algorithm lanes (CcConfig.code: 0 dcqcn, 1 timely, 2 hpcc)
-        is_dcqcn = p["cc_algo"] == 0
-        timely_m = p["cc_algo"] == 1
-        hpcc_m = p["cc_algo"] == 2
-        inv_brtt = one / p["base_rtt"]              # [.., F]
-        u_floor = f(0.01)
     if any_msg:
         arangeL = xp.arange(Lm, dtype=xp.int32)[:, None]       # [L, 1]
         arangeB = xp.arange(HIST_BUCKETS, dtype=xp.int32)[:, None, None]
@@ -1003,20 +1410,6 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             s["lost"] = s["lost"] + lost_f * rec_en
             s["gapped"] = s["gapped"] | (gbn_b & (lost_f > zero))
 
-    def cut(s, fire):
-        """DCQCN on_cnp for flows where ``fire`` holds."""
-        s = dict(s)
-        s["rt"] = xp.where(fire, s["rc"], s["rt"])
-        s["rc"] = xp.where(
-            fire, xp.maximum(p["minr"], s["rc"] * (1.0 - s["alpha"] / 2.0)),
-            s["rc"])
-        s["alpha"] = xp.where(
-            fire, xp.minimum(one, (1.0 - p["g"]) * s["alpha"] + p["g"]),
-            s["alpha"])
-        for k in ("t_us", "byts", "t_stage", "b_stage", "a_tus"):
-            s[k] = xp.where(fire, zero, s[k])
-        return s
-
     def class_tot(q0):
         """Per-(port, TC) occupancy [.., Q, P] from per-flow bytes
         [.., P, F] — one small matmul with the class one-hot."""
@@ -1032,41 +1425,9 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         qm = s["qm"]
         q0 = qm[..., 0, :, :]
         qtc = class_tot(q0)                       # [.., Q, P]
-        budget0 = budget if upf is None else budget * upf
-        # strict-priority budget grants as one fused water-fill stage:
-        # each class takes min(1, left/demand), leftover budget below
-        # 1e-6 of the link budget clamps to zero (rounding crumbs after
-        # a class eats the whole budget must not become micro-byte
-        # trickles for the next class — they would trigger full-size
-        # discrete CNPs downstream); relative, so f32 and f64 backends
-        # agree with the scalar driver on every grant/no-grant decision
-        # (OutputPort.drain).  The ref tier is op for op the unrolled
-        # loop it replaced; pallas/interpret run the VMEM kernel.
+        budget0 = c.budget if upf is None else c.budget * upf
         can_q = st["stage"][k] & ~s["paused"] & (qtc > zero)  # [.., Q, P]
-        frac_q = fused.priority_grants(
-            xp, qtc, can_q if impl == "ref"
-            else xp.where(can_q, one, zero),
-            budget0, budget_crumb, one, zero, impl=impl)
-        if wrr:
-            # weighted water-filling over backlogged unpaused classes,
-            # unrolled Q rounds with the exact op order of
-            # OutputPort._wrr_fracs (float64 reference == scalar driver)
-            rem = xp.where(can_q, qtc, zero)
-            alloc = xp.zeros_like(qtc)
-            bl = budget0
-            for _ in range(N_QOS):
-                wq = xp.where(rem > zero, quantaQ, zero)
-                wsum = wq.sum(-2)                 # [.., P]
-                share = bl[..., None, :] * wq \
-                    / xp.maximum(wsum, tiny)[..., None, :]
-                take = xp.minimum(share, rem)
-                alloc = alloc + take
-                rem = rem - take
-                bl = bl - take.sum(-2)
-                bl = xp.where(bl < budget_crumb, zero, bl)
-            frac_wrr = xp.where(qtc > zero,
-                                alloc / xp.maximum(qtc, tiny), zero)
-            frac_q = xp.where(is_wrr, frac_wrr, frac_q)
+        frac_q = _grants(xp, c, qtc, can_q, budget0)
         # scatter per-class grants to (port, flow); one class per flow,
         # so the matmul contraction has a single nonzero term
         frac_pf = mm(xp.swapaxes(frac_q, -1, -2), clsF)
@@ -1076,7 +1437,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         qm = qm - out
         # sub-1e-9 residues vanish with their marks (the scalar driver's
         # dict-entry cleanup, per drained class)
-        gone = (can_pf > half) & (qm[..., 0, :, :] < eps_q)
+        gone = (can_pf > half) & (qm[..., 0, :, :] < c.eps_q)
         s["qm"] = xp.where(gone[..., None, :, :], zero, qm)
         return s, out
 
@@ -1088,7 +1449,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         q0 = s["qm"][..., 0, :, :]
         qtc = class_tot(q0)                       # [.., Q, P] pre-batch
         tot_q = class_tot(A[..., 0, :, :])
-        space_q = xp.maximum(buf_tc - qtc, zero)
+        space_q = xp.maximum(c.buf_tc - qtc, zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
         scale_pf = mm(xp.swapaxes(scale_q, -1, -2), clsF)
@@ -1101,7 +1462,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         else:
             s["inj_lo"] = s["inj_lo"] - lost.sum(-2)
         s["sw_dropped"] = s["sw_dropped"] + lost.sum((-1, -2))
-        mark_q = ecn_on[..., None, :] & (qtc > kmin_th)
+        mark_q = c.ecn_on[..., None, :] & (qtc > c.kmin_th)
         mark_pf = mm(xp.swapaxes(xp.where(mark_q, one, zero),
                                         -1, -2), clsF)        # [.., P, F]
         dm = xp.where(mark_pf > half,
@@ -1109,18 +1470,6 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         s["ecn_marked"] = s["ecn_marked"] + dm.sum((-1, -2))
         s["qm"] = s["qm"] + take + dm[..., None, :, :] * st["sel1"]
         return s
-
-    fold_at = f(65536.0)
-
-    def fold(s, hi, lo):
-        """Drain a split accumulator's low part into its high part once it
-        outgrows 64 KiB.  Keeping per-tick increments on a small-magnitude
-        accumulator bounds float32 rounding drift to O(10) bytes over a
-        run — tight enough that closed-flow completion thresholds stay
-        meaningful — while costing three element-wise ops per tick."""
-        full = xp.abs(s[lo]) >= fold_at
-        s[hi] = s[hi] + xp.where(full, s[lo], zero)
-        s[lo] = xp.where(full, zero, s[lo])
 
     def step(s, t, it=None):
         # ``t`` is the simulated tick (timers, event windows, fault
@@ -1135,28 +1484,15 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             it = t
         s = dict(s)
         now = (xp.asarray(t, dtype) + one) * fdt
-        fold(s, "injected", "inj_lo")
-        fold(s, "delivered", "deliv_lo")
+        _fold(xp, c, s, "injected", "inj_lo")
+        _fold(xp, c, s, "delivered", "deliv_lo")
 
         # ---- 0. link failure / flap / crash events ------------------------ #
         upf = None
         D0 = None
         route_oh = None
         if dyn:
-            downP = (t >= p["fail_at"]) & (t < p["fail_until"])   # [.., P]
-            edgeP = t == p["fail_at"]
-            if flap:
-                # periodic flaps fold into the same down/edge masks
-                # (Topology.flap_ticks: down for the first `down` ticks
-                # of each `period` cycle from `start`)
-                since = t - p["flap_start"]
-                live = t >= p["flap_start"]
-                downP = downP | (live
-                                 & (since % p["flap_period"]
-                                    < p["flap_down"]))
-                edgeP = edgeP | (live & (since % p["flap_period"] == 0))
-            upf = xp.where(downP, zero, one)
-            failf = xp.where(edgeP, one, zero)
+            upf, failf = _link_windows(xp, c, p, t, flap)
             # in-flight bytes die with the link; fluid go-back-N
             # re-credits them for retransmission (run_fabric step 0)
             lostF = (s["qm"][..., 0, :, :] * failf[..., :, None]).sum(-2)
@@ -1187,7 +1523,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 s[ck] = s[ck] * cz
             s["qos_q"] = s["qos_q"] * cz[..., None, :]
             s["ring"] = s["ring"] * cz[..., None, None, :]
-            s["pfc"] = s["pfc"] & ~(crash_now[..., None, :] if host_tc
+            s["pfc"] = s["pfc"] & ~(crash_now[..., None, :] if c.host_tc
                                     else crash_now)
             s["heavy"] = xp.where(crash_now, -1, s["heavy"])
             # the cleared RNIC gate unpauses the access link this very
@@ -1216,44 +1552,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 return out - dead
 
         # ---- 1. senders: DCQCN advance + offer ---------------------------- #
-        adv = now > p["start"]
-        # the DCQCN timer machinery only moves DCQCN-lane flows; the CC
-        # block after forwarding writes the timely/hpcc rates instead
-        dadv = (adv & is_dcqcn) if any_cc else adv
-        adv_dt = xp.where(dadv, fdt, zero)
-        a_tus = s["a_tus"] + adv_dt
-        a_fire = dadv & (a_tus >= p["a_tmr"])
-        s["alpha"] = xp.where(a_fire, (1.0 - p["g"]) * s["alpha"],
-                              s["alpha"])
-        s["a_tus"] = xp.where(a_fire, zero, a_tus)
-        t_us = s["t_us"] + adv_dt
-        byts = xp.where(dadv, s["byts"] + s["rc"] * bpt, s["byts"])
-        t_fire = dadv & (t_us >= p["r_tmr"])
-        s["t_stage"] = s["t_stage"] + t_fire
-        s["t_us"] = xp.where(t_fire, zero, t_us)
-        b_fire = dadv & (byts >= p["bctr"])
-        s["b_stage"] = s["b_stage"] + b_fire
-        s["byts"] = xp.where(b_fire, zero, byts)
-        fired = t_fire | b_fire
-        stage = xp.minimum(s["t_stage"], s["b_stage"])
-        s["rt"] = xp.where(fired & (stage == p["fth"]),
-                           xp.minimum(p["dline"], s["rt"] + p["ai"]),
-                           s["rt"])
-        s["rt"] = xp.where(fired & (stage > p["fth"]),
-                           xp.minimum(p["dline"], s["rt"] + p["hai"]),
-                           s["rt"])
-        s["rc"] = xp.where(fired,
-                           xp.minimum(p["dline"],
-                                      0.5 * (s["rc"] + s["rt"])),
-                           s["rc"])
-
-        gbps = xp.minimum(s["rc"], linecap)
-        room = xp.maximum(p["burst"] - (s["injected"] + s["inj_lo"]), zero)
-        # burst-train duty cycle: the DCQCN machine keeps running, the
-        # tap only opens during the on-phase (matches SenderHost.offer)
-        active = adv & (~onoff | (xp.fmod(now - p["start"], period)
-                                  < p["on_us"]))
-        offer = xp.where(active, xp.minimum(gbps * bpt, room), zero)
+        offer = _send_rate(xp, c, p, s, now)
         if any_msg:
             # outstanding message window: injection never runs more than
             # W*msg_bytes ahead of delivery (start-of-tick counters, the
@@ -1267,7 +1566,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         off_pf = st["occ"][0] * offer[..., None, :]
         tot_q = class_tot(off_pf)                         # [.., Q, P]
         space_q = xp.maximum(
-            buf_tc - class_tot(s["qm"][..., 0, :, :]), zero)
+            c.buf_tc - class_tot(s["qm"][..., 0, :, :]), zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
         scale_pf = mm(xp.swapaxes(scale_q, -1, -2), clsF)
@@ -1299,18 +1598,18 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 up1 = es('sfp,...p->...sf', st["upP"], upf)
                 up2 = es('sfp,...p->...sf', st["dnP"], upf)
                 upS = st["candS"] & (up1 > half) & (up2 > half)
-                free = xp.where(upS, xp.maximum(bufSF - occS, zero), zero)
+                free = xp.where(upS, xp.maximum(c.bufSF - occS, zero), zero)
                 cur = s["route"]                                  # [.., F]
-                cur_oh = arangeS == cur[..., None, :]             # [.., S, F]
+                cur_oh = c.arangeS == cur[..., None, :]             # [.., S, F]
                 occ_cur = (occS * xp.where(cur_oh, one, zero)).sum(-2)
                 up_cur = (upS & cur_oh).any(-2)
                 any_up = upS.any(-2)
                 # adaptive: least-congested up candidate + hysteresis
-                occ_masked = xp.where(upS, occS, inf)
+                occ_masked = xp.where(upS, occS, c.inf)
                 best = xp.argmin(occ_masked, -2).astype(xp.int32)
                 occ_best = occ_masked.min(-2)
                 adapt = xp.where(
-                    any_up & (~up_cur | (occ_best < occ_cur - hystF)),
+                    any_up & (~up_cur | (occ_best < occ_cur - c.hystF)),
                     best, cur)
                 # weighted ECMP: flowlet-boundary (or dead-path) re-hash
                 # against the free-space-weighted cumulative distribution;
@@ -1318,7 +1617,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 # the pick identical to routing.weighted_pick (modular
                 # reduction of k keeps every product inside int32)
                 kred = k_new % 65536
-                hv = ((arangeF + 1) * 40503 + kred * 9973) % 65536
+                hv = ((c.arangeF + 1) * 40503 + kred * 9973) % 65536
                 hsh = hv.astype(dtype) / f(65536.0)               # [.., F]
                 cum = xp.cumsum(free, -2)
                 tot = cum[..., Sn - 1, :]                         # [.., F]
@@ -1332,7 +1631,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 s["reroutes"] = s["reroutes"] + \
                     xp.where(choice != cur, one, zero)
                 s["route"] = choice
-                ch_oh = xp.where(arangeS == choice[..., None, :],
+                ch_oh = xp.where(c.arangeS == choice[..., None, :],
                                  one, zero)
                 route_oh = ch_oh
                 totS = tot[..., None, :]
@@ -1416,9 +1715,7 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
             arr_m = xp.where(s["gapped"], zero, arr_m)
 
         # ---- 2.2 delay/INT telemetry -> CC zoo updates -------------------- #
-        # end-of-forwarding queue state along each flow's current path,
-        # folded into rtt = base + sum(q/budget) and util = max per-hop
-        # (txRate/B + qlen/(B*T)) — run_fabric's loop as masked lanes
+        # end-of-forwarding queue state along each flow's current path
         if any_cc:
             qP = s["qm"][..., 0, :, :].sum(-1)                # [.., P]
             if dyn and Sn:
@@ -1428,251 +1725,26 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
                 leg1 = leg2 = None
             else:
                 leg1, leg2 = st["occ"][1], st["occ"][2]
-            qd = zero
-            util = zero
-            for leg in (st["occ"][0], leg1, leg2, st["occ"][3]):
-                if leg is None:
-                    continue
-                # [P, F] (static) or [.., P, F] (routed) one-hot gathers
-                q_l = (leg * qP[..., :, None]).sum(-2)        # [.., F]
-                tx_l = (leg * txP[..., :, None]).sum(-2)
-                b_l = (leg * budgetP[..., :, None]).sum(-2)
-                ok = b_l > zero
-                qd = qd + xp.where(ok, q_l / xp.maximum(b_l, tiny), zero)
-                u_l = xp.where(ok, (tx_l + q_l * (fdt * inv_brtt))
-                               / xp.maximum(b_l, tiny), zero)
-                util = xp.maximum(util, u_l)
-            rtt = p["base_rtt"] + qd * fdt
-            ctus = s["cc_tus"] + fdt
-            fire = ctus >= p["cc_upd"]
-            s["cc_tus"] = xp.where(fire, zero, ctus)
-            # Timely: smoothed RTT gradient picks the branch
-            ft = fire & timely_m
-            diff = rtt - s["prev_rtt"]
-            rd_new = (1.0 - p["tl_a"]) * s["rtt_diff"] + p["tl_a"] * diff
-            s["prev_rtt"] = xp.where(ft, rtt, s["prev_rtt"])
-            s["rtt_diff"] = xp.where(ft, rd_new, s["rtt_diff"])
-            grad = rd_new * inv_brtt
-            rc = s["rc"]
-            r_tim = xp.where(
-                rtt < p["t_low"], rc + p["tl_add"],
-                xp.where(rtt > p["t_high"],
-                         rc * (one - p["tl_beta"]
-                               * (one - p["t_high"] / rtt)),
-                         xp.where(grad <= zero, rc + p["tl_add"],
-                                  rc * xp.maximum(
-                                      zero, one - p["tl_beta"] * grad))))
-            rc_tim = xp.minimum(p["line"],
-                                xp.maximum(p["cc_minr"], r_tim))
-            # HPCC: drive max per-hop utilization toward eta
-            fh = fire & hpcc_m
-            mult = xp.clip(p["hp_eta"] / xp.maximum(util, u_floor),
-                           half, f(2.0))
-            rc_hp = xp.minimum(p["line"],
-                               xp.maximum(p["cc_minr"],
-                                          rc * mult + p["hp_ai"]))
-            s["rc"] = xp.where(ft, rc_tim, xp.where(fh, rc_hp, rc))
+            # [P, F] (static) or [.., P, F] (routed) one-hot gathers
+            _cc_update(xp, c, p, s, (
+                ((leg * qP[..., :, None]).sum(-2),
+                 (leg * txP[..., :, None]).sum(-2),
+                 (leg * c.budget[..., :, None]).sum(-2))
+                for leg in (st["occ"][0], leg1, leg2, st["occ"][3])
+                if leg is not None))
 
         # ---- 3. receivers advance one tick (HostDatapath, stacked) -------- #
-        with _scope(xp, RECV_SCOPE):
-            arr_rb = st["recv_onehot"] * arr_b[..., None, :]
-            # QoS-classed arrivals [.., Q, R] (admission class x receiver)
-            arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
-            arr_tot = arr_cr.sum(-2)
-            # admission: RNIC buffer space granted in QoS-priority order —
-            # the second fused priority water-fill (HostDatapath.admit_link)
-            space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
-            acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
-            accepted = acc_cr[..., 0, :]
-            for q_i in range(1, N_QOS):
-                accepted = accepted + acc_cr[..., q_i, :]
-            if flt:
-                # first byte accepted after a crash restart stamps the
-                # crash-recovery latency (run_fabric step 3)
-                rec_hit = (t >= p["crash_until"]) & (accepted > zero) \
-                    & xp.isinf(s["crash_rec"])
-                s["crash_rec"] = xp.where(
-                    rec_hit, now - p["crash_at"].astype(dtype) * fdt,
-                    s["crash_rec"])
-            s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
-            s["qos_q"] = s["qos_q"] + acc_cr
-
-            ws = p["qp_bytes"] + s["resident"]
-            miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
-            s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
-            ddio_bw = xp.where(miss > 1e-9,
-                               xp.minimum(p["pcie"],
-                                          avail_dram / (2.0 * miss + tiny)),
-                               p["pcie"])
-            # drain budget granted in QoS-priority order; under Jet pool
-            # pressure (< cache_safe free) the LOW class spills to DRAM (§5)
-            budget = xp.where(jet, jet_cap, ddio_bw * bpt)
-            pool_free = xp.maximum(zero, p["pool"] - s["resident"])
-            spill = jet & (pool_free / p["pool"] < p["safe"])
-            pf = xp.where(jet, pool_free, inf)
-            drained = pool_drained = fallback = zero
-            new_q = []
-            for q_i in range(N_QOS):
-                qq = s["qos_q"][..., q_i, :]
-                take = xp.minimum(xp.minimum(qq, budget), pf)
-                if q_i == N_QOS - 1:        # LOW spills instead of waiting
-                    take = xp.where(spill, xp.minimum(qq, budget), take)
-                    spilled = xp.where(spill, take, zero)
-                else:
-                    spilled = zero
-                pf = pf - (take - spilled)
-                budget = budget - take
-                new_q.append(qq - take)
-                drained = drained + take
-                pool_drained = pool_drained + (take - spilled)
-                fallback = fallback + spilled
-            s["qos_q"] = xp.stack(new_q, -2)
-            s["nic_dram"] = s["nic_dram"] + \
-                xp.where(jet, fallback, drained * 2.0 * miss)
-            s["mem_fb"] = s["mem_fb"] + fallback
-            strag_part = pool_drained * strag_share
-            parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
-                             -2)
-            # ring layout [H, 2, R]: the write is a contiguous leading-axis
-            # slice update, which XLA aliases in place inside the scan carry
-            s["ring"] = ring_set(s["ring"], it % H, parts)
-            s["resident"] = s["resident"] + pool_drained
-            s["strag_res"] = s["strag_res"] + strag_part
-            s["drained"] = s["drained"] + drained
-
-            idx = (it - p["d2"]) % H                  # [.., 2, R]
-            r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
-                                    -3)[..., 0, :, :]
-            r2 = xp.where(it >= p["d2"], r2, zero)
-            for j, is_strag in ((0, False), (1, True)):
-                r = r2[..., j, :]
-                void = xp.minimum(r, s["esc_debt"])
-                s["esc_debt"] = s["esc_debt"] - void
-                r = r - void
-                repay = xp.minimum(void, s["repl_debt"])
-                s["repl_debt"] = s["repl_debt"] - repay
-                s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
-                s["resident"] = xp.maximum(zero, s["resident"] - r)
-                if is_strag:
-                    s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
-
-            # Jet escape ladder (paper Algorithm 1)
-            avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-            esc_on = jet & (avail < p["safe"])
-            can_rep = s["repl_mem"] < p["mem_esc"]
-            x_rep = xp.where(esc_on & can_rep,
-                             xp.maximum(zero,
-                                        xp.minimum(s["strag_res"],
-                                                   p["mem_esc"]
-                                                   - s["repl_mem"])),
-                             zero)
-            s["resident"] = s["resident"] - x_rep
-            s["strag_res"] = s["strag_res"] - x_rep
-            s["esc_debt"] = s["esc_debt"] + x_rep
-            s["repl_debt"] = s["repl_debt"] + x_rep
-            s["repl_mem"] = s["repl_mem"] + x_rep
-            s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
-            s["replaces"] = s["replaces"] + (x_rep > zero)
-            x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
-            s["resident"] = s["resident"] - x_cop
-            s["strag_res"] = s["strag_res"] - x_cop
-            s["esc_debt"] = s["esc_debt"] + x_cop
-            s["esc_dram"] = s["esc_dram"] + x_cop
-            s["copies"] = s["copies"] + (x_cop > zero)
-            avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-            in_danger = esc_on & (avail2 < p["danger"])
-            s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt,
-                                    s["ecn_tus"])
-            esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
-            s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
-            s["cnps"] = s["cnps"] + esc_fire
-            s["ecns"] = s["ecns"] + esc_fire
-            s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
-            s["pool_peak"] = xp.maximum(s["pool_peak"],
-                                        xp.where(jet, s["resident"], zero))
-
-            # receiver congestion signalling
-            q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
-            if host_tc:
-                # per-class receiver gate ([.., Q, R] pause state): per-TC
-                # points watermark each class's occupancy of its 1/N_QOS
-                # buffer partition (ReceiverHost's arithmetic, op for op),
-                # legacy points see the total occupancy in every row —
-                # identical decisions to the scalar whole-link gate
-                frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
-                sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
-                s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
-                                                sel > xoffQ)
-                pfc_any = s["pfc"].any(-2)
-            else:
-                s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
-                                                q_frac > p["xoff"])
-                pfc_any = s["pfc"]
-            s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
-            cnp_tus = s["cnp_tus"] + fdt
-            wm_fire = wm_en & (q_frac > p["ecn_th"]) \
-                & (cnp_tus >= p["cnp_iv"])
-            s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
-            s["cnps"] = s["cnps"] + wm_fire
+        rx = _receive(xp, c, st, p, s, arr_b, t, now, it, flt)
 
         # ---- 4. feedback routes back to the senders ----------------------- #
-        # per-class acceptance share: a flow recovers the share its own
-        # admission class received (matches HostDatapath.admit_link)
-        share_cr = xp.where(arr_cr > zero,
-                            acc_cr / xp.maximum(arr_cr, tiny), zero)
-        deliv = arr_b * share_cr[..., st["cls_of"], st["recv_of"]]
-        s["deliv_lo"] = s["deliv_lo"] + deliv
-        # RNIC tail drops are retransmitted too (fluid RC / the ledger)
-        if flt:
-            ledger(s, arr_b - deliv)
-        else:
-            s["inj_lo"] = s["inj_lo"] - (arr_b - deliv)
-        s["completion"] = xp.where(
-            xp.isinf(s["completion"])
-            & (s["delivered"] + s["deliv_lo"] >= p["burst_done"]),
-            now, s["completion"])
-
-        # receiver CNPs hit the heaviest recently-arriving flow (lowest
-        # flow id on ties); with nothing arriving the previous target
-        # stays throttled, as in run_fabric/run_sim
-        with _scope(xp, RECV_SCOPE):
-            has_arr = arr_tot > zero
-            heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
-            s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
-        is_heavy = arangeF == s["heavy"][..., st["recv_of"]]
-        f_esc = is_heavy & esc_fire[..., st["recv_of"]]
-        f_wm = is_heavy & wm_fire[..., st["recv_of"]]
-        # switch ECN marks -> per-flow CNPs, paced per DCQCN NP
-        s["backlog"] = s["backlog"] + arr_m
-        pace_tus = s["pace_tus"] + fdt
-        pace_fire = (s["backlog"] > zero) & (pace_tus >= p["cnp_iv_f"])
-        s["pace_tus"] = xp.where(pace_fire, zero, pace_tus)
-        s["backlog"] = xp.where(pace_fire, zero, s["backlog"])
-        # CNP propagation ring [Hc, 3, F]: notifications generated this
-        # tick (slot t % Hc) cut their sender its *own* cnp_delay ticks
-        # later — the delay is per flow, so the read index is a [F]
-        # gather (slot (t - delay_f) % Hc; Hc > every delay, so for
-        # t < delay the read lands on a slot not yet written, which
-        # still holds zero)
-        fires = xp.stack([xp.where(f_esc, one, zero),
-                          xp.where(f_wm, one, zero),
-                          xp.where(pace_fire, one, zero)], -2)
-        s["cring"] = ring_set(s["cring"], it % Hc, fires)
-        cidx = (it - p["cnp_dly"]) % Hc
-        due = xp.take_along_axis(s["cring"], cidx[..., None, None, :],
-                                 -3)[..., 0, :, :]
-        for j in range(3):
-            fire_c = due[..., j, :] > half
-            if any_cc:
-                # timely/hpcc ignore CNPs (CongestionControl.on_cnp)
-                fire_c = fire_c & is_dcqcn
-            s = cut(s, fire_c)
+        deliv = _feedback(xp, c, st, p, s, arr_b, arr_m, rx, now, it,
+                          ledger if flt else None)
 
         # ---- 5. per-priority PFC pause propagation ------------------------ #
         q0 = s["qm"][..., 0, :, :]
-        frac_occ = class_tot(q0) / buf_tc                     # [.., Q, P]
-        s["asserted"] = can_assert[..., None, :] & \
-            xp.where(s["asserted"], frac_occ >= sxon, frac_occ > sxoff)
+        frac_occ = class_tot(q0) / c.buf_tc                   # [.., Q, P]
+        s["asserted"] = c.can_assert[..., None, :] & \
+            xp.where(s["asserted"], frac_occ >= c.sxon, frac_occ > c.sxoff)
         # a flow contributes a pause iff its own class is over watermark
         # at the port it is queued in: scatter the per-class assert state
         # back to (port, flow), then to that flow's class on its ingress
@@ -1705,11 +1777,10 @@ def _make_step(xp, ring_set, st, p, dt: float, H: int, dtype, Hc: int = 1,
         # the receiver RNIC gate: whole access link (legacy — broadcast
         # across the class axis) or per admission class (host_pfc_per_tc,
         # [.., Q, R] state gathered per stage-3 port)
-        if host_tc:
-            rx_gate = s["pfc"][..., st["owner_clamp"]] & st["owner_valid"]
+        rx_gate = s["pfc"][..., st["owner_clamp"]] & st["owner_valid"]
+        if c.host_tc:
             s["paused"] = link_paused | rx_gate
         else:
-            rx_gate = s["pfc"][..., st["owner_clamp"]] & st["owner_valid"]
             s["paused"] = link_paused | rx_gate[..., None, :]
 
         # ---- 6. message-layer crossings (MessageTracker, stacked) --------- #
@@ -1790,10 +1861,12 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
                       Hc: int = 1, opts: Optional[dict] = None):
     """Build the sparse-incidence ``step(state, t)`` (pod-scale fabrics).
 
-    Tick semantics match :func:`_make_step` exactly, but queue state
-    lives as ``[.., 2, S, F]`` *slot* entries (S = 6 tier-ordered stage
-    slots; slot ``(s, f)`` is queued at port ``port_of[s, f]``) instead
-    of the dense ``[.., 2, P, F]`` port x flow matrix.  Per-(port, TC)
+    Tick semantics match :func:`_make_step` exactly — both call the same
+    layout-free stages (:func:`_send_rate`, :func:`_cc_update`,
+    :func:`_receive`, :func:`_feedback`) — but queue state lives as
+    ``[.., 2, S, F]`` *slot* entries (S = 6 tier-ordered stage slots;
+    slot ``(s, f)`` is queued at port ``port_of[s, f]``) instead of the
+    dense ``[.., 2, P, F]`` port x flow matrix.  Per-(port, TC)
     totals are segment-sums over the S*F (slot, flow) entries and every
     per-port decision (drain fraction, buffer scale, ECN knee, PFC
     assert) comes back to the flows as a padded flat gather at the
@@ -1812,12 +1885,9 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
     with a clear error under ``sparse=True``).
     """
     o = opts or {}
-    wrr, host_tc = o.get("wrr", False), o.get("host_tc", False)
     any_cc = o.get("cc", False)
-    impl = o.get("impl", "ref") if xp is not np else "ref"
     fail = "fail_at" in p
     flap = "flap_start" in p
-    f = dtype
     S = _STAGES_SP
     F = int(st["recv_of"].shape[0])
     P = int(st["stage"].shape[-1])
@@ -1852,66 +1922,16 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
         xf = xp.concatenate([x_qp, pad], -1)
         return xf.reshape(xf.shape[:-2] + (QPpad,))[..., idx]
 
-    bpt = f(1e9 / 8.0 * dt * 1e-6)       # bytes per (Gbps * tick)
-    fdt = f(dt)
-    zero, one, tiny = f(0.0), f(1.0), f(1e-30)
-    half, inf = f(0.5), f(np.inf)
-    eps_q = f(1e-9)
-    arangeF = xp.arange(F, dtype=xp.int32)
-    budget = p["gbps"] * bpt
-    budget_crumb = budget * f(1e-6)
-    buf_tc = p["buf"][..., None, None]
-    kmin_th = p["kmin"][..., None] * buf_tc
-    ecn_on = p["ecn_en"] > 0.5
-    can_assert = p["can_assert"] > 0.5
-    sxoff = p["sw_xoff"][..., None]
-    sxon = p["sw_xon"][..., None]
-    onoff = p["off_us"] > zero
-    period = xp.where(onoff, p["on_us"] + p["off_us"], one)
-    jet = p["jet"] > 0.5
-    avail_dram = xp.maximum(zero, p["membw"] - p["cpu_bw"])
-    jet_cap = xp.minimum(p["pcie"], p["line1"] * 4.0) * bpt
-    strag_share = xp.where(jet, p["sfrac"], zero)
-    inv_knee = one / (p["knee"] * p["ddio"])
-    rx_pfc_en = p["pfc_en"] > 0.5
-    wm_en = p["wm_cnp"] > 0.5
-    linecap = xp.minimum(p["line"], p["cap"])
-    if wrr:
-        quantaQ = p["quanta"][..., None]            # [.., Q, 1]
-        is_wrr = (p["sched"] == 1)[..., None, None]  # [.., 1, 1]
-    if host_tc:
-        hpfc_b = (p["hpfc"] > half)[..., None, :]   # [.., 1, R]
-        rx_pfc_tc = rx_pfc_en[..., None, :]
-        xoffQ = p["xoff"][..., None, :]
-        xonQ = p["xon"][..., None, :]
+    c = _tick_consts(xp, ring_set, st, p, dt, H, dtype, Hc, o)
+    zero, one, tiny, half, fdt = c.zero, c.one, c.tiny, c.half, c.fdt
     if any_cc:
-        # algorithm lanes (CcConfig.code: 0 dcqcn, 1 timely, 2 hpcc)
-        is_dcqcn = p["cc_algo"] == 0
-        timely_m = p["cc_algo"] == 1
-        hpcc_m = p["cc_algo"] == 2
-        inv_brtt = one / p["base_rtt"]              # [.., F]
-        u_floor = f(0.01)
         # padded per-port budget for the telemetry gathers (column P =
         # "slot unused", budget 0 -> the leg drops out, as the dense
         # engine's zero one-hot columns)
         budget_pad = xp.concatenate(
-            [budget, xp.zeros(budget.shape[:-1] + (1,), budget.dtype)],
+            [c.budget, xp.zeros(c.budget.shape[:-1] + (1,), c.budget.dtype)],
             -1)
         po_flat = st["port_of"].reshape(S * F)      # [S*F] flat slots
-
-    def cut(s, fire):
-        """DCQCN on_cnp for flows where ``fire`` holds."""
-        s = dict(s)
-        s["rt"] = xp.where(fire, s["rc"], s["rt"])
-        s["rc"] = xp.where(
-            fire, xp.maximum(p["minr"], s["rc"] * (1.0 - s["alpha"] / 2.0)),
-            s["rc"])
-        s["alpha"] = xp.where(
-            fire, xp.minimum(one, (1.0 - p["g"]) * s["alpha"] + p["g"]),
-            s["alpha"])
-        for k in ("t_us", "byts", "t_stage", "b_stage", "a_tus"):
-            s[k] = xp.where(fire, zero, s[k])
-        return s
 
     def qtc_all(qm):
         """Full per-(TC, port) occupancy [.., Q, P]: one scatter of all
@@ -1926,35 +1946,15 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
         qm = s["qm"]
         qrow = qm[..., :, k, :]                   # [.., 2, F]
         qtc = segQ(qrow[..., 0, :], st["qp_idx"][k])
-        budget0 = budget if upf is None else budget * upf
+        budget0 = c.budget if upf is None else c.budget * upf
         can_q = st["stage"][k] & ~s["paused"] & (qtc > zero)
-        frac_q = fused.priority_grants(
-            xp, qtc, can_q if impl == "ref"
-            else xp.where(can_q, one, zero),
-            budget0, budget_crumb, one, zero, impl=impl)
-        if wrr:
-            rem = xp.where(can_q, qtc, zero)
-            alloc = xp.zeros_like(qtc)
-            bl = budget0
-            for _ in range(N_QOS):
-                wq = xp.where(rem > zero, quantaQ, zero)
-                wsum = wq.sum(-2)                 # [.., P]
-                share = bl[..., None, :] * wq \
-                    / xp.maximum(wsum, tiny)[..., None, :]
-                take = xp.minimum(share, rem)
-                alloc = alloc + take
-                rem = rem - take
-                bl = bl - take.sum(-2)
-                bl = xp.where(bl < budget_crumb, zero, bl)
-            frac_wrr = xp.where(qtc > zero,
-                                alloc / xp.maximum(qtc, tiny), zero)
-            frac_q = xp.where(is_wrr, frac_wrr, frac_q)
+        frac_q = _grants(xp, c, qtc, can_q, budget0)
         frac_f = gQ(frac_q, st["qp_idx"][k])      # [.., F]
         out = qrow * frac_f[..., None, :]
         left = qrow - out
         # sub-1e-9 residues vanish with their marks (dense drain)
         can_f = gQ(xp.where(can_q, one, zero), st["qp_idx"][k])
-        gone = (can_f > half) & (left[..., 0, :] < eps_q)
+        gone = (can_f > half) & (left[..., 0, :] < c.eps_q)
         left = xp.where(gone[..., None, :], zero, left)
         s["qm"] = qm - (qrow - left)[..., :, None, :] * st["row_oh"][k]
         return s, out
@@ -1966,14 +1966,14 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
         dq = st["dq_idx"][k]
         qtc = qtc_all(s["qm"])
         tot_q = segQ(A[..., 0, :], dq)
-        space_q = xp.maximum(buf_tc - qtc, zero)
+        space_q = xp.maximum(c.buf_tc - qtc, zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
         take = A * gQ(scale_q, dq)[..., None, :]
         lost = (A - take)[..., 0, :]
         s["inj_lo"] = s["inj_lo"] - lost
         s["sw_dropped"] = s["sw_dropped"] + lost.sum(-1)
-        mark_q = ecn_on[..., None, :] & (qtc > kmin_th)
+        mark_q = c.ecn_on[..., None, :] & (qtc > c.kmin_th)
         mark_f = gQ(xp.where(mark_q, one, zero), dq)
         dm = xp.where(mark_f > half,
                       take[..., 0, :] - take[..., 1, :], zero)
@@ -1983,35 +1983,18 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             * st["nxt_oh"][k]
         return s
 
-    fold_at = f(65536.0)
-
-    def fold(s, hi, lo):
-        full = xp.abs(s[lo]) >= fold_at
-        s[hi] = s[hi] + xp.where(full, s[lo], zero)
-        s[lo] = xp.where(full, zero, s[lo])
-
     def step(s, t, it=None):
         if it is None:
             it = t
         s = dict(s)
         now = (xp.asarray(t, dtype) + one) * fdt
-        fold(s, "injected", "inj_lo")
-        fold(s, "delivered", "deliv_lo")
+        _fold(xp, c, s, "injected", "inj_lo")
+        _fold(xp, c, s, "delivered", "deliv_lo")
 
         # ---- 0. link failure / flap windows ------------------------------- #
         upf = None
         if fail:
-            downP = (t >= p["fail_at"]) & (t < p["fail_until"])   # [.., P]
-            edgeP = t == p["fail_at"]
-            if flap:
-                since = t - p["flap_start"]
-                live = t >= p["flap_start"]
-                downP = downP | (live
-                                 & (since % p["flap_period"]
-                                    < p["flap_down"]))
-                edgeP = edgeP | (live & (since % p["flap_period"] == 0))
-            upf = xp.where(downP, zero, one)
-            failf = xp.where(edgeP, one, zero)
+            upf, failf = _link_windows(xp, c, p, t, flap)
             failp = xp.concatenate(
                 [failf, xp.zeros(failf.shape[:-1] + (1,), failf.dtype)],
                 -1)
@@ -2022,46 +2005,11 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             s["qm"] = s["qm"] * (one - fail_sf)[..., None, :, :]
 
         # ---- 1. senders: DCQCN advance + offer ---------------------------- #
-        adv = now > p["start"]
-        # the DCQCN timer machinery only moves DCQCN-lane flows; the CC
-        # block after forwarding writes the timely/hpcc rates instead
-        dadv = (adv & is_dcqcn) if any_cc else adv
-        adv_dt = xp.where(dadv, fdt, zero)
-        a_tus = s["a_tus"] + adv_dt
-        a_fire = dadv & (a_tus >= p["a_tmr"])
-        s["alpha"] = xp.where(a_fire, (1.0 - p["g"]) * s["alpha"],
-                              s["alpha"])
-        s["a_tus"] = xp.where(a_fire, zero, a_tus)
-        t_us = s["t_us"] + adv_dt
-        byts = xp.where(dadv, s["byts"] + s["rc"] * bpt, s["byts"])
-        t_fire = dadv & (t_us >= p["r_tmr"])
-        s["t_stage"] = s["t_stage"] + t_fire
-        s["t_us"] = xp.where(t_fire, zero, t_us)
-        b_fire = dadv & (byts >= p["bctr"])
-        s["b_stage"] = s["b_stage"] + b_fire
-        s["byts"] = xp.where(b_fire, zero, byts)
-        fired = t_fire | b_fire
-        stage = xp.minimum(s["t_stage"], s["b_stage"])
-        s["rt"] = xp.where(fired & (stage == p["fth"]),
-                           xp.minimum(p["dline"], s["rt"] + p["ai"]),
-                           s["rt"])
-        s["rt"] = xp.where(fired & (stage > p["fth"]),
-                           xp.minimum(p["dline"], s["rt"] + p["hai"]),
-                           s["rt"])
-        s["rc"] = xp.where(fired,
-                           xp.minimum(p["dline"],
-                                      0.5 * (s["rc"] + s["rt"])),
-                           s["rc"])
-
-        gbps = xp.minimum(s["rc"], linecap)
-        room = xp.maximum(p["burst"] - (s["injected"] + s["inj_lo"]), zero)
-        active = adv & (~onoff | (xp.fmod(now - p["start"], period)
-                                  < p["on_us"]))
-        offer = xp.where(active, xp.minimum(gbps * bpt, room), zero)
+        offer = _send_rate(xp, c, p, s, now)
         # source-side backpressure at the NIC queue (slot 0's port)
         qtcI = qtc_all(s["qm"])
         tot_q = segQ(offer, st["qp_idx"][0])
-        space_q = xp.maximum(buf_tc - qtcI, zero)
+        space_q = xp.maximum(c.buf_tc - qtcI, zero)
         scale_q = xp.where(tot_q > space_q,
                            space_q / xp.maximum(tot_q, tiny), one)
         take_f = offer * gQ(scale_q, st["qp_idx"][0])
@@ -2101,216 +2049,23 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             v = s["qm"][..., 0, :, :]
             qPp = seg_sum(v.reshape(v.shape[:-2] + (S * F,)), po_flat,
                           Ppad)                               # [.., P+1]
-            qd = zero
-            util = zero
-            for k in range(S):
-                if not st["stage_any"][k]:
-                    continue
-                po_k = st["port_of"][k]                       # [F]
-                q_l = qPp[..., po_k]
-                tx_l = txPp[..., po_k]
-                b_l = budget_pad[..., po_k]
-                ok = b_l > zero
-                qd = qd + xp.where(ok, q_l / xp.maximum(b_l, tiny), zero)
-                u_l = xp.where(ok, (tx_l + q_l * (fdt * inv_brtt))
-                               / xp.maximum(b_l, tiny), zero)
-                util = xp.maximum(util, u_l)
-            rtt = p["base_rtt"] + qd * fdt
-            ctus = s["cc_tus"] + fdt
-            fire = ctus >= p["cc_upd"]
-            s["cc_tus"] = xp.where(fire, zero, ctus)
-            # Timely: smoothed RTT gradient picks the branch
-            ft = fire & timely_m
-            diff = rtt - s["prev_rtt"]
-            rd_new = (1.0 - p["tl_a"]) * s["rtt_diff"] + p["tl_a"] * diff
-            s["prev_rtt"] = xp.where(ft, rtt, s["prev_rtt"])
-            s["rtt_diff"] = xp.where(ft, rd_new, s["rtt_diff"])
-            grad = rd_new * inv_brtt
-            rc = s["rc"]
-            r_tim = xp.where(
-                rtt < p["t_low"], rc + p["tl_add"],
-                xp.where(rtt > p["t_high"],
-                         rc * (one - p["tl_beta"]
-                               * (one - p["t_high"] / rtt)),
-                         xp.where(grad <= zero, rc + p["tl_add"],
-                                  rc * xp.maximum(
-                                      zero, one - p["tl_beta"] * grad))))
-            rc_tim = xp.minimum(p["line"],
-                                xp.maximum(p["cc_minr"], r_tim))
-            # HPCC: drive max per-hop utilization toward eta
-            fh = fire & hpcc_m
-            mult = xp.clip(p["hp_eta"] / xp.maximum(util, u_floor),
-                           half, f(2.0))
-            rc_hp = xp.minimum(p["line"],
-                               xp.maximum(p["cc_minr"],
-                                          rc * mult + p["hp_ai"]))
-            s["rc"] = xp.where(ft, rc_tim, xp.where(fh, rc_hp, rc))
+            _cc_update(xp, c, p, s, (
+                (qPp[..., po_k], txPp[..., po_k], budget_pad[..., po_k])
+                for po_k in (st["port_of"][k] for k in range(S)
+                             if st["stage_any"][k])))
 
         # ---- 3. receivers advance one tick (HostDatapath, stacked) -------- #
-        with _scope(xp, RECV_SCOPE):
-            arr_rb = st["recv_onehot"] * arr_b[..., None, :]
-            arr_cr = (st["cls_recv"] * arr_b[..., None, None, :]).sum(-1)
-            arr_tot = arr_cr.sum(-2)
-            space_r = xp.maximum(p["rnic_buf"] - s["qos_q"].sum(-2), zero)
-            acc_cr = fused.priority_admit(xp, arr_cr, space_r, impl=impl)
-            accepted = acc_cr[..., 0, :]
-            for q_i in range(1, N_QOS):
-                accepted = accepted + acc_cr[..., q_i, :]
-            s["rnic_drop"] = s["rnic_drop"] + (arr_tot - accepted)
-            s["qos_q"] = s["qos_q"] + acc_cr
-
-            ws = p["qp_bytes"] + s["resident"]
-            miss = xp.clip((ws - p["ddio"]) * inv_knee, zero, one)
-            s["miss_sum"] = s["miss_sum"] + xp.where(jet, zero, miss)
-            ddio_bw = xp.where(miss > 1e-9,
-                               xp.minimum(p["pcie"],
-                                          avail_dram / (2.0 * miss + tiny)),
-                               p["pcie"])
-            budget_r = xp.where(jet, jet_cap, ddio_bw * bpt)
-            pool_free = xp.maximum(zero, p["pool"] - s["resident"])
-            spill = jet & (pool_free / p["pool"] < p["safe"])
-            pf = xp.where(jet, pool_free, inf)
-            drained = pool_drained = fallback = zero
-            new_q = []
-            for q_i in range(N_QOS):
-                qq = s["qos_q"][..., q_i, :]
-                take = xp.minimum(xp.minimum(qq, budget_r), pf)
-                if q_i == N_QOS - 1:        # LOW spills instead of waiting
-                    take = xp.where(spill, xp.minimum(qq, budget_r), take)
-                    spilled = xp.where(spill, take, zero)
-                else:
-                    spilled = zero
-                pf = pf - (take - spilled)
-                budget_r = budget_r - take
-                new_q.append(qq - take)
-                drained = drained + take
-                pool_drained = pool_drained + (take - spilled)
-                fallback = fallback + spilled
-            s["qos_q"] = xp.stack(new_q, -2)
-            s["nic_dram"] = s["nic_dram"] + \
-                xp.where(jet, fallback, drained * 2.0 * miss)
-            s["mem_fb"] = s["mem_fb"] + fallback
-            strag_part = pool_drained * strag_share
-            parts = xp.stack([pool_drained * (1.0 - strag_share), strag_part],
-                             -2)
-            s["ring"] = ring_set(s["ring"], it % H, parts)
-            s["resident"] = s["resident"] + pool_drained
-            s["strag_res"] = s["strag_res"] + strag_part
-            s["drained"] = s["drained"] + drained
-
-            idx = (it - p["d2"]) % H                  # [.., 2, R]
-            r2 = xp.take_along_axis(s["ring"], idx[..., None, :, :],
-                                    -3)[..., 0, :, :]
-            r2 = xp.where(it >= p["d2"], r2, zero)
-            for j, is_strag in ((0, False), (1, True)):
-                r = r2[..., j, :]
-                void = xp.minimum(r, s["esc_debt"])
-                s["esc_debt"] = s["esc_debt"] - void
-                r = r - void
-                repay = xp.minimum(void, s["repl_debt"])
-                s["repl_debt"] = s["repl_debt"] - repay
-                s["repl_mem"] = xp.maximum(zero, s["repl_mem"] - repay)
-                s["resident"] = xp.maximum(zero, s["resident"] - r)
-                if is_strag:
-                    s["strag_res"] = xp.maximum(zero, s["strag_res"] - r)
-
-            # Jet escape ladder (paper Algorithm 1)
-            avail = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-            esc_on = jet & (avail < p["safe"])
-            can_rep = s["repl_mem"] < p["mem_esc"]
-            x_rep = xp.where(esc_on & can_rep,
-                             xp.maximum(zero,
-                                        xp.minimum(s["strag_res"],
-                                                   p["mem_esc"]
-                                                   - s["repl_mem"])),
-                             zero)
-            s["resident"] = s["resident"] - x_rep
-            s["strag_res"] = s["strag_res"] - x_rep
-            s["esc_debt"] = s["esc_debt"] + x_rep
-            s["repl_debt"] = s["repl_debt"] + x_rep
-            s["repl_mem"] = s["repl_mem"] + x_rep
-            s["esc_dram"] = s["esc_dram"] + 0.1 * x_rep
-            s["replaces"] = s["replaces"] + (x_rep > zero)
-            x_cop = xp.where(esc_on & ~can_rep, s["strag_res"], zero)
-            s["resident"] = s["resident"] - x_cop
-            s["strag_res"] = s["strag_res"] - x_cop
-            s["esc_debt"] = s["esc_debt"] + x_cop
-            s["esc_dram"] = s["esc_dram"] + x_cop
-            s["copies"] = s["copies"] + (x_cop > zero)
-            avail2 = xp.maximum(zero, p["pool"] - s["resident"]) / p["pool"]
-            in_danger = esc_on & (avail2 < p["danger"])
-            s["ecn_tus"] = xp.where(in_danger, s["ecn_tus"] + fdt,
-                                    s["ecn_tus"])
-            esc_fire = in_danger & (s["ecn_tus"] >= p["cnp_iv"])
-            s["ecn_tus"] = xp.where(esc_fire, zero, s["ecn_tus"])
-            s["cnps"] = s["cnps"] + esc_fire
-            s["ecns"] = s["ecns"] + esc_fire
-            s["pool_sum"] = s["pool_sum"] + xp.where(jet, s["resident"], zero)
-            s["pool_peak"] = xp.maximum(s["pool_peak"],
-                                        xp.where(jet, s["resident"], zero))
-
-            # receiver congestion signalling
-            q_frac = s["qos_q"].sum(-2) / p["rnic_buf"]
-            if host_tc:
-                frac_c = s["qos_q"] / (p["rnic_buf"] / f(N_QOS))[..., None, :]
-                sel = xp.where(hpfc_b, frac_c, q_frac[..., None, :])
-                s["pfc"] = rx_pfc_tc & xp.where(s["pfc"], sel >= xonQ,
-                                                sel > xoffQ)
-                pfc_any = s["pfc"].any(-2)
-            else:
-                s["pfc"] = rx_pfc_en & xp.where(s["pfc"], q_frac >= p["xon"],
-                                                q_frac > p["xoff"])
-                pfc_any = s["pfc"]
-            s["pfc_us"] = s["pfc_us"] + xp.where(pfc_any, fdt, zero)
-            cnp_tus = s["cnp_tus"] + fdt
-            wm_fire = wm_en & (q_frac > p["ecn_th"]) \
-                & (cnp_tus >= p["cnp_iv"])
-            s["cnp_tus"] = xp.where(wm_fire, zero, cnp_tus)
-            s["cnps"] = s["cnps"] + wm_fire
+        rx = _receive(xp, c, st, p, s, arr_b, t, now, it, False)
 
         # ---- 4. feedback routes back to the senders ----------------------- #
-        share_cr = xp.where(arr_cr > zero,
-                            acc_cr / xp.maximum(arr_cr, tiny), zero)
-        deliv = arr_b * share_cr[..., st["cls_of"], st["recv_of"]]
-        s["deliv_lo"] = s["deliv_lo"] + deliv
-        s["inj_lo"] = s["inj_lo"] - (arr_b - deliv)
-        s["completion"] = xp.where(
-            xp.isinf(s["completion"])
-            & (s["delivered"] + s["deliv_lo"] >= p["burst_done"]),
-            now, s["completion"])
-
-        with _scope(xp, RECV_SCOPE):
-            has_arr = arr_tot > zero
-            heavy_new = xp.argmax(arr_rb, -1).astype(xp.int32)
-            s["heavy"] = xp.where(has_arr, heavy_new, s["heavy"])
-        is_heavy = arangeF == s["heavy"][..., st["recv_of"]]
-        f_esc = is_heavy & esc_fire[..., st["recv_of"]]
-        f_wm = is_heavy & wm_fire[..., st["recv_of"]]
-        s["backlog"] = s["backlog"] + arr_m
-        pace_tus = s["pace_tus"] + fdt
-        pace_fire = (s["backlog"] > zero) & (pace_tus >= p["cnp_iv_f"])
-        s["pace_tus"] = xp.where(pace_fire, zero, pace_tus)
-        s["backlog"] = xp.where(pace_fire, zero, s["backlog"])
-        fires = xp.stack([xp.where(f_esc, one, zero),
-                          xp.where(f_wm, one, zero),
-                          xp.where(pace_fire, one, zero)], -2)
-        s["cring"] = ring_set(s["cring"], it % Hc, fires)
-        cidx = (it - p["cnp_dly"]) % Hc
-        due = xp.take_along_axis(s["cring"], cidx[..., None, None, :],
-                                 -3)[..., 0, :, :]
-        for j in range(3):
-            fire_c = due[..., j, :] > half
-            if any_cc:
-                # timely/hpcc ignore CNPs (CongestionControl.on_cnp)
-                fire_c = fire_c & is_dcqcn
-            s = cut(s, fire_c)
+        _feedback(xp, c, st, p, s, arr_b, arr_m, rx, now, it)
 
         # ---- 5. per-priority PFC pause propagation ------------------------ #
         q0s = s["qm"][..., 0, :, :]                           # [.., S, F]
         qtcP = qtc_all(s["qm"])
-        frac_occ = qtcP / buf_tc
-        s["asserted"] = can_assert[..., None, :] & \
-            xp.where(s["asserted"], frac_occ >= sxon, frac_occ > sxoff)
+        frac_occ = qtcP / c.buf_tc
+        s["asserted"] = c.can_assert[..., None, :] & \
+            xp.where(s["asserted"], frac_occ >= c.sxon, frac_occ > c.sxoff)
         # a slot contributes a pause iff its flow's class is asserted at
         # its own port; the pause targets the slot's ingress port on the
         # flow's class — one gather + one scatter over the S*F entries
@@ -2333,7 +2088,7 @@ def _make_step_sparse(xp, ring_set, st, p, dt: float, H: int, dtype,
             xp.where(link_paused, fdt, zero)
         s["ever_paused"] = s["ever_paused"] | link_any
         rx_gate = s["pfc"][..., st["owner_clamp"]] & st["owner_valid"]
-        if host_tc:
+        if c.host_tc:
             s["paused"] = link_paused | rx_gate
         else:
             s["paused"] = link_paused | rx_gate[..., None, :]
@@ -2702,6 +2457,15 @@ def _opts(fsp: FabricSweepParams, impl: str = "ref") -> dict:
             "impl": impl}
 
 
+def _make_tick(xp, ring_set, st, p, fsp: FabricSweepParams, dtype,
+               impl: str = "ref"):
+    """``step(state, t)`` of ``fsp``'s engine: the sparse tick for a
+    sparse pack, the dense one otherwise."""
+    mk = _make_step_sparse if fsp.sparse else _make_step
+    return mk(xp, ring_set, st, p, fsp.dt_us, fsp.ring_len, dtype,
+              fsp.cnp_ring, _opts(fsp, impl))
+
+
 def _run_numpy(fsp: FabricSweepParams, dtype=np.float64,
                adaptive: Optional[AdaptiveConfig] = None):
     p = _np_params(fsp, dtype)
@@ -2711,9 +2475,7 @@ def _run_numpy(fsp: FabricSweepParams, dtype=np.float64,
         ring[..., idx, :, :] = v
         return ring
 
-    mk = _make_step_sparse if fsp.sparse else _make_step
-    step = mk(np, ring_set, st, p, fsp.dt_us, fsp.ring_len, dtype,
-              fsp.cnp_ring, _opts(fsp))
+    step = _make_tick(np, ring_set, st, p, fsp, dtype)
     s = _init_state(np, (fsp.n_points,), fsp, p, dtype)
     if adaptive is None:
         for t in range(fsp.ticks):
@@ -2846,16 +2608,14 @@ def _program(fsp: FabricSweepParams, unroll: int, impl: str,
 
     dtype = jnp.float32
     st = _static(fsp, jnp, dtype)
-    ticks, H, Hc = fsp.ticks, fsp.ring_len, fsp.cnp_ring
+    ticks = fsp.ticks
     lead = (fsp.n_points,)
 
     def ring_set(ring, idx, v):
         return ring.at[..., idx, :, :].set(v)
 
     def one_point(s0, p):
-        mk = _make_step_sparse if fsp.sparse else _make_step
-        step = mk(jnp, ring_set, st, p, fsp.dt_us, H, dtype, Hc,
-                  _opts(fsp, impl))
+        step = _make_tick(jnp, ring_set, st, p, fsp, dtype, impl)
 
         def body(s, t):
             return step(s, t), None
@@ -2933,7 +2693,7 @@ def _jax_adaptive_program(fsp: FabricSweepParams, cfg: AdaptiveConfig,
 
     dtype = jnp.float32
     st = _static(fsp, jnp, dtype)
-    ticks, H, Hc = fsp.ticks, fsp.ring_len, fsp.cnp_ring
+    ticks = fsp.ticks
 
     def ring_set(ring, idx, v):
         return ring.at[..., idx, :, :].set(v)
@@ -2943,8 +2703,7 @@ def _jax_adaptive_program(fsp: FabricSweepParams, cfg: AdaptiveConfig,
         # vmapped: the stride is a whole-grid reduction, so every point
         # advances in lockstep (a per-point stride would desynchronize
         # the shared ring clock)
-        step = _make_step(jnp, ring_set, st, p, fsp.dt_us, H, dtype, Hc,
-                          _opts(fsp, impl))
+        step = _make_tick(jnp, ring_set, st, p, fsp, dtype, impl)
         stride = fused.make_stride_fn(jnp, fsp, p, _opts(fsp, impl), cfg,
                                       dtype)
 
@@ -2983,6 +2742,39 @@ def _run_jax_adaptive(fsp: FabricSweepParams, cfg: AdaptiveConfig,
     res = _results({k: np.asarray(v) for k, v in final.items()}, fsp)
     res["adaptive_iterations"] = np.full(fsp.n_points, int(iters))
     return res
+
+
+def pick_sparse(scenarios: Sequence, incidence: str = "auto") -> bool:
+    """Whether ``scenarios`` run on the sparse engine under ``incidence``
+    (``"dense"``, ``"sparse"`` or ``"auto"``): ``"auto"`` picks sparse
+    exactly when a topology has a super-spine tier."""
+    if incidence not in ("auto", "dense", "sparse"):
+        raise ValueError(f"unknown incidence {incidence!r}")
+    return incidence == "sparse" or (
+        incidence == "auto"
+        and any(bool(s.topology.super_spines) for s in scenarios))
+
+
+def run_packed(fsp: FabricSweepParams, backend: str = "jax",
+               unroll="auto", impl: str = "auto",
+               adaptive: Optional[AdaptiveConfig] = None, device=None,
+               record: Optional[dict] = None) -> Dict[str, np.ndarray]:
+    """Run a packed grid on ``backend`` and return its metrics.  ``numpy``
+    is the float64 reference; ``jax`` resolves the kernel tier ``impl``
+    (:func:`repro.fabric.fused.resolve_impl`) and runs the scan program
+    on ``device`` with its spans timed into ``record``, or the adaptive
+    macro-tick loop when ``adaptive`` is given."""
+    if fsp.sparse and adaptive is not None:
+        raise ValueError("adaptive_dt macro-ticking is dense-engine "
+                         "only; run sparse grids at the fine tick")
+    if backend == "numpy":
+        return _run_numpy(fsp, adaptive=adaptive)
+    if backend == "jax":
+        ri = fused.resolve_impl(impl)
+        if adaptive is not None:
+            return _run_jax_adaptive(fsp, adaptive, ri)
+        return _run_jax(fsp, unroll, ri, device, record)
+    raise ValueError(f"unknown backend {backend!r}")
 
 
 def run_fabric_sweep(scenarios: Sequence, backend: str = "jax",
@@ -3035,23 +2827,9 @@ def run_fabric_sweep(scenarios: Sequence, backend: str = "jax",
     monolithic grid's program structure and reproduces its results
     bit-for-bit (see :meth:`FabricSweepParams.envelope`).
     """
-    if incidence not in ("auto", "dense", "sparse"):
-        raise ValueError(f"unknown incidence {incidence!r}")
-    sparse = incidence == "sparse" or (
-        incidence == "auto"
-        and any(bool(s.topology.super_spines) for s in scenarios))
-    fsp = FabricSweepParams.from_scenarios(scenarios, sparse=sparse,
-                                           envelope=envelope)
+    fsp = FabricSweepParams.from_scenarios(
+        scenarios, sparse=pick_sparse(scenarios, incidence),
+        envelope=envelope)
     cfg = adaptive if adaptive is not None \
         else (AdaptiveConfig() if adaptive_dt else None)
-    if fsp.sparse and cfg is not None:
-        raise ValueError("adaptive_dt macro-ticking is dense-engine "
-                         "only; run sparse grids at the fine tick")
-    if backend == "numpy":
-        return _run_numpy(fsp, adaptive=cfg)
-    if backend == "jax":
-        ri = fused.resolve_impl(impl)
-        if cfg is not None:
-            return _run_jax_adaptive(fsp, cfg, ri)
-        return _run_jax(fsp, unroll, ri)
-    raise ValueError(f"unknown backend {backend!r}")
+    return run_packed(fsp, backend, unroll, impl, cfg)

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.core.datapath import QoS
+from repro.core.simulator import testbed_100g
 from repro.fabric import scenarios as SC
 from repro.fabric.fabric import FabricConfig, Flow
 from repro.fabric.messages import (HIST_MAX_US, LogHistogram,
@@ -320,18 +321,72 @@ class TestPodEquivalence:
         assert float(out["uplink_util_max"][0]) > 0.0
 
 
+def _incast_modes():
+    scens, _ = SC.fabric_grid(
+        lambda mode: SC.incast(n_senders=4, mode=mode, burst_mb=0.2,
+                               sim_time_s=SIM_S),
+        mode=["ddio", "jet"])
+    return scens
+
+
+def _receive_branches():
+    """Two 2-tier points through every branch of the receive stage both
+    engines share: a Jet receiver whose small pool spills LOW-class bytes
+    and escapes by replace, then copy, then ECN, under per-TC host PFC;
+    and a DDIO receiver with cache misses under whole-link host PFC.
+    The switches run per-TC PFC with buffers small enough to pause."""
+    def point(kind):
+        sc = SC.incast(n_senders=4, mode="jet", pfc=True, burst_mb=0.5,
+                       sim_time_s=SIM_S)
+        for i, fl in enumerate(sc.flows):
+            fl.qos = (QoS.LOW, QoS.NORMAL, QoS.HIGH)[i % 3]
+
+        def recv(host):
+            if kind == "jet":
+                return testbed_100g("jet", pfc_enabled=True,
+                                    host_pfc_per_tc=True,
+                                    jet_pool_bytes=256 << 10,
+                                    straggler_frac=0.3,
+                                    mem_esc_bytes=64 << 10)
+            return testbed_100g("ddio", pfc_enabled=True,
+                                cpu_membw_gbps=1990.0)
+        sc.fabric.receiver_cfg = recv
+        sc.fabric.switch = SwitchConfig(pfc_enabled=True, per_tc=True,
+                                        port_buffer_bytes=128 << 10)
+        return sc
+    return [point("jet"), point("ddio")]
+
+
 class TestSparseEngineContract:
-    def test_two_tier_sparse_matches_dense_exactly(self):
-        scens, _ = SC.fabric_grid(
-            lambda mode: SC.incast(n_senders=4, mode=mode, burst_mb=0.2,
-                                   sim_time_s=SIM_S),
-            mode=["ddio", "jet"])
+    @pytest.mark.parametrize("grid, reached", [
+        (_incast_modes, ()),
+        (_receive_branches, ("replaces", "copies", "ecns", "mem_fb",
+                             "miss_sum", "pfc_us", "pause_us")),
+    ], ids=["incast", "receive_branches"])
+    def test_two_tier_sparse_matches_dense_exactly(self, grid, reached,
+                                                   monkeypatch):
+        from repro.fabric import vector as V
+        carry = {}
+        results = V._results
+
+        def keep(s, fsp):
+            carry["sparse" if fsp.sparse else "dense"] = s
+            return results(s, fsp)
+        monkeypatch.setattr(V, "_results", keep)
+        scens = grid()
         dense = run_fabric_sweep(scens, backend="numpy",
                                  incidence="dense")
         sparse = run_fabric_sweep(scens, backend="numpy",
                                   incidence="sparse")
-        for k in KEYS:
+        both = set(dense) & set(sparse)
+        assert set(KEYS) <= both
+        for k in sorted(both):
             np.testing.assert_array_equal(dense[k], sparse[k], err_msg=k)
+        # the grid reaches the branches it is meant to, in both engines
+        for k in reached:
+            assert np.asarray(carry["dense"][k]).max() > 0, k
+            np.testing.assert_array_equal(carry["dense"][k],
+                                          carry["sparse"][k], err_msg=k)
 
     def test_dense_rejects_super_spine_topology(self):
         sc = SC.pod_incast(pods=2, leaves_per_pod=2, hosts_per_leaf=2,

@@ -95,9 +95,7 @@ def _np_carry(fsp, ticks=200):
         ring[..., idx, :, :] = v
         return ring
 
-    mk = V._make_step_sparse if fsp.sparse else V._make_step
-    step = mk(np, ring_set, st, p, fsp.dt_us, fsp.ring_len, np.float32,
-              fsp.cnp_ring, V._opts(fsp))
+    step = V._make_tick(np, ring_set, st, p, fsp, np.float32)
     s = V._init_state(np, (fsp.n_points,), fsp, p, np.float32)
     for t in range(min(ticks, fsp.ticks)):
         s = step(s, t)
@@ -150,9 +148,7 @@ def _unpacked_run(fsp):
         return ring.at[..., idx, :, :].set(v)
 
     def one_point(s0, p):
-        mk = V._make_step_sparse if fsp.sparse else V._make_step
-        step = mk(jnp, ring_set, st, p, fsp.dt_us, fsp.ring_len,
-                  jnp.float32, fsp.cnp_ring, V._opts(fsp, impl))
+        step = V._make_tick(jnp, ring_set, st, p, fsp, jnp.float32, impl)
         s, _ = jax.lax.scan(lambda s, t: (step(s, t), None), s0,
                             jnp.arange(fsp.ticks, dtype=jnp.int32),
                             unroll=V.pick_unroll("auto"))
